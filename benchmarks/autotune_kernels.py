@@ -10,10 +10,10 @@ pins a mode (docs/KERNELS.md §Execution policy).
 The representative shapes mirror the two call-site families the committed
 figs exercise: the bench stream (wiki-bench: 520 nodes, batch 100 -> 200
 touched occurrences, d_mem 32 — benchmarks/common.bench_stream) and the
-launch defaults (d_mem 100, batch 500 -> 1000 occurrences). Each winner is
-stamped with the memory-roofline floor (roofline.kernel_ceiling_ms) so an
-entry sitting orders of magnitude above bandwidth reads as interpreter /
-dispatch overhead at a glance.
+launch defaults (d_mem 100, batch 500 -> 1000 occurrences). On a TPU each
+winner is stamped with the memory-roofline floor (roofline.kernel_ceiling_ms,
+from the chip's published peaks) so an entry sitting orders of magnitude
+above bandwidth reads as dispatch overhead at a glance.
 
     PYTHONPATH=src python -m benchmarks.autotune_kernels [--force] [--fast]
 """
@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from benchmarks import common, roofline
@@ -95,9 +96,12 @@ def run(fast: bool = False, seeds: int = 1, force: bool = False):
         entry = dict(autotune.autotune(name, args, backend=backend,
                                        extra_kw=extra_kw or None,
                                        force=force))
-        ceiling = roofline.kernel_ceiling_ms(name, args, backend=backend,
-                                             extra_kw=extra_kw or None)
-        entry["ceiling_ms"] = round(ceiling, 6)
+        # a roofline floor exists only for a chip with published peaks
+        ceiling = (roofline.kernel_ceiling_ms(
+            name, args, jax.devices()[0].device_kind,
+            extra_kw=extra_kw or None) if backend == "tpu" else None)
+        if ceiling is not None:
+            entry["ceiling_ms"] = round(ceiling, 6)
         autotune.record(backend, name, args, entry)
         rows.append({"kernel": name, "sig": autotune.shape_sig(args),
                      "mode": entry["mode"],

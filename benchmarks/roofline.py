@@ -7,6 +7,7 @@ import json
 import pathlib
 
 from benchmarks import common
+from repro.launch.mesh import DRY_RUN_KIND, chip_peaks
 
 DRYRUN_DIR = pathlib.Path(__file__).resolve().parent.parent / "results" / "dryrun"
 
@@ -31,33 +32,25 @@ LEVERS = {
 }
 
 
-# Nominal memory bandwidth per backend (bytes/s) for the kernel-level
-# roofline floor below: single-core DRAM stream for CPU, HBM for TPU. The
-# floor is a sanity anchor for autotune winners (an entry orders of
-# magnitude above it is dispatch/interpreter overhead, not bandwidth), not
-# a calibrated machine model.
-MEM_BW_BYTES = {"cpu": 2.0e10, "tpu": 1.2e12}
-
-
-def kernel_ceiling_ms(name: str, args, backend: str = "cpu",
+def kernel_ceiling_ms(name: str, args, device_kind: str,
                       extra_kw: dict | None = None) -> float:
     """Memory-roofline floor (ms) for one registry kernel at these args:
-    every input read once + every output written once at the backend's
-    nominal bandwidth. Output shapes come from jax.eval_shape of the
+    every input read once + every output written once at the chip's
+    published HBM bandwidth. Output shapes come from jax.eval_shape of the
     kernel's oracle, so no computation runs. benchmarks/autotune_kernels.py
-    stamps this next to each measured winner."""
+    stamps this next to each winner measured on a chip."""
     import functools
 
     import jax
 
     from repro.kernels import ops as kops
+    bw = chip_peaks(device_kind)["hbm_bytes_per_s"]
     spec = kops.get_kernel(name)
     fn = functools.partial(spec.oracle or spec.ref, **(extra_kw or {}))
     outs = jax.eval_shape(fn, *args)
     arrays = [a for a in list(args) + jax.tree.leaves(outs)
               if hasattr(a, "shape") and hasattr(a, "dtype")]
     nbytes = sum(int(a.size) * a.dtype.itemsize for a in arrays)
-    bw = MEM_BW_BYTES.get(backend, MEM_BW_BYTES["cpu"])
     return nbytes / bw * 1e3
 
 
@@ -101,9 +94,8 @@ def run(fast: bool = False, seeds: int = 1):
         # analytic compute floor: XLA cost_analysis counts while-loop bodies
         # once, so scanned layer stacks under-report flops by ~n_layers;
         # MODEL_FLOPS/chips/peak corrects the compute term.
-        import repro.launch.mesh as mesh_lib
         c_model = (d.get("model_flops_global", 0.0) / d["chips"]
-                   / mesh_lib.PEAK_FLOPS_BF16)
+                   / chip_peaks(DRY_RUN_KIND)["flops_bf16"])
         c = max(d["compute_s"], c_model)
         terms = {"compute": c, "memory": d["memory_s"],
                  "collective": d["collective_s"]}

@@ -201,7 +201,10 @@ def compact_unique(nodes, t, budget: int):
     """
     n = nodes.shape[0]
     budget = int(min(budget, n))
-    order = jnp.lexsort((t, nodes))
+    # lexsort((t, nodes)) as two stable one-key sorts: the same permutation,
+    # which the TPU compiler builds ~3x faster at frontier sizes (~30k keys)
+    order = jnp.argsort(t, stable=True)
+    order = order[jnp.argsort(nodes[order], stable=True)]
     ns, ts = nodes[order], t[order]
     new = jnp.concatenate([jnp.ones((1,), bool),
                            (ns[1:] != ns[:-1]) | (ts[1:] != ts[:-1])])

@@ -46,9 +46,9 @@ BLOCK_CANDIDATES: dict[str, tuple[int, ...]] = {
     "block_m": (64, 128, 256, 512),
     "block_b": (16, 32, 64),
     "block_i": (64, 128, 256),
-    # embed_attn: neighbour slots gathered per grid step (K is padded to a
-    # multiple, so every candidate is valid at every K)
-    "block_k": (1, 2, 4, 8),
+    # embed_attn: parent rows per grid step (R is padded to a multiple, so
+    # every candidate is valid at every R)
+    "block_r": (8, 16, 32, 64),
 }
 
 
@@ -155,7 +155,10 @@ def tune(name: str, args: Sequence, *, backend: str | None = None,
          extra_kw: dict | None = None) -> dict:
     """Measure every candidate at these args and return the winning entry
     {"mode", "blocks", "ms", "swept"}. Candidates that fail to build (e.g.
-    a tile larger than the padded shape supports) are skipped."""
+    a tile larger than the padded shape supports) are skipped, except a
+    compiled Pallas candidate on a TPU: that is the path the chip runs, so
+    its failure raises with the kernel's name and the compiler's error
+    instead of letting the oracle win unseen."""
     from repro.kernels import ops
     backend = backend or ops.backend()
     extra = dict(extra_kw or {})
@@ -165,7 +168,12 @@ def tune(name: str, args: Sequence, *, backend: str | None = None,
                                **cand["blocks"], **extra)
         try:
             ms = float(timer(fn, args, cand))
-        except Exception:
+        except Exception as e:
+            if backend == "tpu" and cand["mode"] == "compiled":
+                raise RuntimeError(
+                    f"autotune: compiled Pallas kernel {name!r} (blocks "
+                    f"{cand['blocks']}) failed on tpu at sig "
+                    f"{shape_sig(args)}: {e}") from e
             continue
         swept += 1
         if best is None or ms < best["ms"]:

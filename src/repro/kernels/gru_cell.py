@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
+
 
 def _gru_kernel(x_ref, h_ref, w_ref, u_ref, b_ref, out_ref):
     x = x_ref[...]
@@ -52,7 +54,7 @@ def _gru_cell_pallas(x, h, w, u, b, *, block_m: int = 128,
             pl.BlockSpec((3 * d,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((block_m, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((mm, d), h.dtype),
+        out_shape=tiling.out_struct((mm, d), h.dtype, x, h),
         interpret=interpret,
     )(x, h, w, u, b)
     return out[:m]

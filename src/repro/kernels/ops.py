@@ -148,7 +148,7 @@ _register(KernelSpec(
 _register(KernelSpec(
     name="memory_update_table",
     impl=_mu.memory_update_table, ref=ref.memory_update_table_ref,
-    blocks={},
+    blocks={"block_m": 32},
     doc="touched-row gather + fused GRU/PRES update + table scatter-back "
         "in ONE pass (aliased (N, D) table, docs/KERNELS.md)"))
 _register(KernelSpec(
@@ -161,7 +161,7 @@ _register(KernelSpec(
     doc="TGN temporal neighbour attention (softmax stays in VMEM)"))
 _register(KernelSpec(
     name="embed_attn", impl=_ea.embed_attn, ref=ref.embed_attn_ref,
-    blocks={"block_k": 1},
+    blocks={"block_r": 16},
     doc="dedup-frontier embedding layer: unique-table gather + time-encode "
         "+ QKV + masked softmax in one pass (docs/KERNELS.md §embed_attn)"))
 _register(KernelSpec(

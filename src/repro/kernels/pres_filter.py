@@ -15,14 +15,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
+
 
 def _filter_kernel(s_prev_ref, s_meas_ref, dmean_ref, dt_ref, gamma_ref,
                    fused_ref, delta_ref, *, clip, delta_mode):
     s_prev = s_prev_ref[...].astype(jnp.float32)
     s_meas = s_meas_ref[...].astype(jnp.float32)
     dmean = dmean_ref[...].astype(jnp.float32)
-    dt = dt_ref[...].astype(jnp.float32)[:, None]
-    gamma = gamma_ref[0]
+    dt = dt_ref[...]
+    gamma = gamma_ref[0, 0]
     step = jnp.clip(dt * dmean, -clip, clip)
     s_pred = s_prev + step
     fused = (1.0 - gamma) * s_pred + gamma * s_meas
@@ -41,13 +43,10 @@ def _pres_filter_pallas(s_prev, s_meas, delta_mean, dt, gamma, *,
     """s_prev/s_meas/delta_mean: (M, D); dt: (M,); gamma: scalar.
     Returns (fused (M, D), delta_rate (M, D))."""
     m, d = s_prev.shape
-    pad_m = (-m) % block_m
-    if pad_m:
-        pad2 = lambda a: jnp.pad(a, ((0, pad_m), (0, 0)))
-        s_prev, s_meas, delta_mean = map(pad2, (s_prev, s_meas, delta_mean))
-        dt = jnp.pad(dt, (0, pad_m), constant_values=1.0)
+    s_prev, s_meas, delta_mean, dt = tiling.pad_rows(
+        block_m, s_prev, s_meas, delta_mean, dt.astype(jnp.float32)[:, None])
     mm = s_prev.shape[0]
-    gamma_arr = jnp.reshape(gamma.astype(jnp.float32), (1,))
+    like = (s_prev, s_meas, delta_mean, dt)
     fused, delta = pl.pallas_call(
         functools.partial(_filter_kernel, clip=clip, delta_mode=delta_mode),
         grid=(mm // block_m,),
@@ -55,19 +54,20 @@ def _pres_filter_pallas(s_prev, s_meas, delta_mean, dt, gamma, *,
             pl.BlockSpec((block_m, d), lambda i: (i, 0)),
             pl.BlockSpec((block_m, d), lambda i: (i, 0)),
             pl.BlockSpec((block_m, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_m,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec((block_m, 1), lambda i: (i, 0)),
+            tiling.SMEM_SCALAR,
         ],
         out_specs=[
             pl.BlockSpec((block_m, d), lambda i: (i, 0)),
             pl.BlockSpec((block_m, d), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((mm, d), s_prev.dtype),
-            jax.ShapeDtypeStruct((mm, d), jnp.float32),
+            tiling.out_struct((mm, d), s_prev.dtype, *like),
+            tiling.out_struct((mm, d), jnp.float32, *like),
         ],
         interpret=interpret,
-    )(s_prev, s_meas, delta_mean, dt, gamma_arr)
+    )(s_prev, s_meas, delta_mean, dt,
+      jnp.reshape(gamma.astype(jnp.float32), (1, 1)))
     return fused[:m], delta[:m]
 
 

@@ -228,12 +228,14 @@ def run_pair(arch_id: str, shape_name: str, multi_pod: bool,
     # CAVEAT: XLA cost_analysis counts a while-loop body ONCE, so scanned
     # layer stacks under-report HLO flops/bytes by ~n_layers; the analytic
     # MODEL_FLOPS floor (6ND/2ND per chip) corrects the compute term.
-    result["compute_hlo_s"] = flops / mesh_lib.PEAK_FLOPS_BF16
-    result["compute_model_s"] = (mf / chips) / mesh_lib.PEAK_FLOPS_BF16
+    peaks = mesh_lib.chip_peaks(mesh_lib.DRY_RUN_KIND)
+    result["compute_hlo_s"] = flops / peaks["flops_bf16"]
+    result["compute_model_s"] = (mf / chips) / peaks["flops_bf16"]
     result["compute_s"] = max(result["compute_hlo_s"],
                               result["compute_model_s"])
-    result["memory_s"] = bytes_accessed / mesh_lib.HBM_BW
-    result["collective_s"] = coll["total_bytes"] / mesh_lib.ICI_BW
+    result["memory_s"] = bytes_accessed / peaks["hbm_bytes_per_s"]
+    result["collective_s"] = (coll["total_bytes"]
+                              / peaks["ici_bytes_per_s_per_link"])
     terms = {"compute": result["compute_s"], "memory": result["memory_s"],
              "collective": result["collective_s"]}
     result["bottleneck"] = max(terms, key=terms.get)

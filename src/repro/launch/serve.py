@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.graph import datasets
 from repro.graph.datasets import SPECS
+from repro.launch import compile_cache
 from repro.models.mdgnn import MDGNNConfig, init_params, init_state
 from repro.serve import MicroBatcher, ServeEngine, replay
 
@@ -213,6 +214,7 @@ def main(argv=None):
     ap.add_argument("--zoo", default=None, help="serve a zoo arch instead")
     ap.add_argument("--steps", type=int, default=16)
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.zoo:
         serve_zoo(args.zoo, args.steps)
     else:

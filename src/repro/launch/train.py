@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.graph import datasets
 from repro.graph.datasets import SPECS
+from repro.launch import compile_cache
 from repro.models.mdgnn import MDGNNConfig, init_params, init_state
 from repro.optim import adamw
 from repro.train import loop, pipeline, scan
@@ -107,6 +108,7 @@ def main(argv=None):
     ap.add_argument("--trace-steps", type=int, default=8,
                     help="step-dispatch window length for --trace-dir")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     streamed = args.event_store is not None
     if streamed:

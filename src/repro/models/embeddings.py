@@ -29,6 +29,7 @@ bit-exactly to the historical single-layer path at n_layers=1.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import jax
@@ -122,7 +123,9 @@ def neighbor_attention(q, k, v, valid, cfg):
         valid = jnp.repeat(valid, h, axis=0)
     if cfg.use_kernels:
         from repro.kernels import ops as kops
-        agg = kops.neighbor_attn(q, k, v, valid, mode=cfg.kernels_mode)
+        from repro.train import routing
+        agg = routing.replicated(cfg, functools.partial(
+            kops.neighbor_attn, mode=cfg.kernels_mode))(q, k, v, valid)
     else:
         agg = _sdpa_single_head(q, k, v, valid)
     if h > 1:
@@ -178,11 +181,12 @@ def _tgn_layer_compact(params, layer_params, h_self, h_child, t_self,
     dt = t_self[:, None] - child["t_edge"]
     if cfg.use_kernels:
         from repro.kernels import ops as kops
-        agg = kops.embed_attn(
+        from repro.train import routing
+        agg = routing.replicated(cfg, functools.partial(
+            kops.embed_attn, n_heads=cfg.n_heads, mode=cfg.kernels_mode))(
             h_self, h_child, child["inverse"].reshape(rows, kk), dt,
             child["valid"], params["time"]["w"], params["time"]["b"],
-            layer_params["wq"], layer_params["wk"], layer_params["wv"],
-            n_heads=cfg.n_heads, mode=cfg.kernels_mode)
+            layer_params["wq"], layer_params["wk"], layer_params["wv"])
     else:
         h_nbr = annotate.events(
             h_child[child["inverse"]]).reshape(rows, kk, -1)

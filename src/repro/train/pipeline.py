@@ -31,6 +31,7 @@ bit-exact with the historical loop (pinned in tests/test_pipeline.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 
 import jax
@@ -106,9 +107,10 @@ def stale_read_table(cfg: MDGNNConfig, pres_state, pstate: PipelineState,
     if cfg.use_kernels:
         from repro.kernels import ops as kops
         dmean = pres.mixture_mean(pres_state, pres_ids)
-        filled = kops.pres_predict(pstate.read_mem.astype(jnp.float32),
-                                   dmean, scale, clip=cfg.pres_clip,
-                                   mode=cfg.kernels_mode)
+        from repro.train import routing
+        filled = routing.replicated(cfg, functools.partial(
+            kops.pres_predict, clip=cfg.pres_clip, mode=cfg.kernels_mode))(
+            pstate.read_mem.astype(jnp.float32), dmean, scale)
     else:
         filled = pres.predict(pres_state, pstate.read_mem.astype(jnp.float32),
                               scale, pres_ids, clip=cfg.pres_clip)

@@ -43,9 +43,10 @@ The per-batch protocol (`sharded_memory_and_pres`) is ONE shard_map region:
    them in batch order.
 
 Everything returned by the shard_map is axis-sharded (out_specs mention
-"shard"), which keeps check_rep's replication discipline and gives exact
-collective transposes for the gradient path (loss -> embedding view ->
-table scatter -> reverse route -> GRU/message params).
+"shard"), which keeps shard_map's replication check (`check_vma`)
+satisfied and gives exact collective transposes for the gradient path
+(loss -> embedding view -> table scatter -> reverse route -> GRU/message
+params).
 """
 from __future__ import annotations
 
@@ -54,7 +55,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import batching, pres
@@ -155,6 +155,17 @@ def replicate(tree, n_shards: int):
 # ---------------------------------------------------------------------------
 # Natural-layout read views (inside jit)
 # ---------------------------------------------------------------------------
+
+
+def replicated(cfg: MDGNNConfig, fn):
+    """`fn` run whole on every shard over replicated operands — how a
+    Pallas kernel, which the SPMD partitioner cannot split, reads the
+    replicated natural-layout views inside a sharded step. Identity when
+    cfg.n_shards == 1."""
+    if cfg.n_shards <= 1:
+        return fn
+    return jax.shard_map(fn, mesh=get_mesh(cfg.n_shards), in_specs=P(),
+                         out_specs=P())
 
 
 def natural_rows(cfg: MDGNNConfig, x, n_rows: int):
@@ -411,8 +422,8 @@ def sharded_memory_and_pres(params, cfg: MDGNNConfig, state, prev_batch,
 
     spec_n = P(AXIS)
     p_specs = jax.tree.map(lambda _: P(), params)
-    out = shard_map(
-        body, mesh,
+    out = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(AXIS, None), spec_n, P(AXIS, None), spec_n, spec_n,
                   spec_n, P(AXIS, None), spec_n, spec_n, p_specs),
         out_specs=(P(AXIS, None), spec_n, P(AXIS, None), P(AXIS, None),
@@ -460,8 +471,8 @@ def sharded_ring_append(cfg: MDGNNConfig, bufs, ptr, nodes, values, mask):
             bufs_l, ptr_l, nodes_c // n, values, mask & own)
 
     v_specs = jax.tree.map(lambda _: P(), values)
-    return shard_map(
-        body, mesh,
+    return jax.shard_map(
+        body, mesh=mesh,
         in_specs=(_ring_specs(bufs), P(AXIS), P(), v_specs, P()),
         out_specs=(_ring_specs(bufs), P(AXIS)),
     )(bufs, ptr, nodes, values, mask)
@@ -501,8 +512,8 @@ def sharded_tracker_update(cfg: MDGNNConfig, pres_state, track_ids, delta,
             jnp.zeros_like(ids_c), mask & own)
         return st.n, st.xi, st.psi
 
-    pn, pxi, ppsi = shard_map(
-        body, mesh,
+    pn, pxi, ppsi = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(AXIS, None), P(AXIS, None, None), P(AXIS, None, None),
                   P(), P(), P()),
         out_specs=(P(AXIS, None), P(AXIS, None, None), P(AXIS, None, None)),

@@ -202,10 +202,10 @@ def test_shape_sig_distinguishes_shape_and_dtype():
 def test_embedding_kernels_expose_swept_blocks(tmp_cache):
     """The embedding-path kernels must participate in the block sweep:
     neighbor_attn's block_m is a registry default (not impl_only) and
-    embed_attn sweeps block_k, so the autotune cache can pick tiles."""
+    embed_attn sweeps block_r, so the autotune cache can pick tiles."""
     from repro.kernels import ops
     for name, key in (("neighbor_attn", "block_m"), ("embed_attn",
-                                                     "block_k")):
+                                                     "block_r")):
         assert key in ops.get_kernel(name).blocks
         cands = autotune.candidates(name, backend="cpu")
         swept = {c["blocks"].get(key) for c in cands
@@ -215,6 +215,18 @@ def test_embedding_kernels_expose_swept_blocks(tmp_cache):
         assert swept == expected
 
 
+def test_tune_raises_when_compiled_candidate_fails_on_tpu(tmp_cache):
+    """On a TPU a compiled candidate that Mosaic refuses must surface with
+    the kernel's name and the error, not quietly lose to the oracle."""
+    def timer(fn, args, cand, repeats=3):
+        if cand["mode"] == "compiled":
+            raise ValueError("Mosaic failed to compile TPU kernel: boom")
+        return 1.0
+
+    with pytest.raises(RuntimeError, match="'gru_cell'.*Mosaic failed"):
+        autotune.tune("gru_cell", _gru_args(), backend="tpu", timer=timer)
+
+
 def test_tune_raises_when_every_candidate_fails(tmp_cache):
     def failing_timer(fn, args, cand, repeats=3):
         raise RuntimeError("boom")
@@ -222,3 +234,20 @@ def test_tune_raises_when_every_candidate_fails(tmp_cache):
     with pytest.raises(RuntimeError, match="no candidate"):
         autotune.tune("gru_cell", _gru_args(), backend="cpu",
                       timer=failing_timer)
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Roofline floors come from one table of published chip peaks keyed
+    by device_kind; a kind without published peaks is an error, never a
+    fallback bandwidth."""
+    from benchmarks import roofline
+    v5e = roofline.chip_peaks("TPU v5 lite")
+    assert v5e["flops_bf16"] == 197e12 and v5e["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError, match="cpu"):
+        roofline.chip_peaks("cpu")
+    args = _gru_args(m=64)
+    nbytes = sum(int(a.size) * a.dtype.itemsize for a in args) + 64 * 16 * 4
+    assert roofline.kernel_ceiling_ms("gru_cell", args, "TPU v5 lite") == (
+        pytest.approx(nbytes / 819e9 * 1e3))
+    with pytest.raises(KeyError):
+        roofline.kernel_ceiling_ms("gru_cell", args, "cpu")

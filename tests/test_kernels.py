@@ -462,14 +462,14 @@ def _embed_attn_args(r, k, u, seed=0, d_self=8, d_tab=8, d_time=4, e=8):
             jnp.asarray(rng.normal(size=(d_tab + d_time, e)), jnp.float32))
 
 
-@pytest.mark.parametrize("r,k,h,bk", [(4, 4, 1, 1), (4, 4, 2, 2),
-                                      (3, 5, 2, 2),   # K % block_k != 0
-                                      (2, 3, 1, 4)])  # block_k > K
+@pytest.mark.parametrize("r,k,h,bk", [(8, 4, 1, 8), (16, 4, 2, 8),
+                                      (13, 5, 2, 8),  # R % block_r != 0
+                                      (2, 3, 1, 16)])  # block_r > R
 def test_embed_attn_matches_ref(r, k, h, bk):
-    """Interpret-mode Pallas (scalar-prefetch gather + online softmax)
-    against the pure-jnp oracle, including padded neighbour blocks."""
+    """Interpret-mode Pallas (DMA row gather + online softmax) against the
+    pure-jnp oracle, including padded parent-row blocks."""
     args = _embed_attn_args(r, k, u=r + 3, seed=r * k + h)
-    got = ops.embed_attn(*args, n_heads=h, block_k=bk, interpret=True)
+    got = ops.embed_attn(*args, n_heads=h, block_r=bk, interpret=True)
     want = ref.embed_attn_ref(*args, n_heads=h)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
@@ -479,7 +479,7 @@ def test_embed_attn_all_invalid_rows():
     (the online-softmax accumulator never sees a live slot)."""
     args = list(_embed_attn_args(5, 4, u=6, seed=3))
     args[4] = jnp.zeros((5, 4), bool)
-    got = ops.embed_attn(*args, n_heads=2, block_k=2, interpret=True)
+    got = ops.embed_attn(*args, n_heads=2, block_r=8, interpret=True)
     want = ref.embed_attn_ref(*args, n_heads=2)
     assert bool(jnp.all(jnp.isfinite(got)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
@@ -499,12 +499,16 @@ def test_embed_attn_grads_match_oracle():
 
     diff_args = tuple(args[i] for i in argnums)
     gk = jax.grad(loss(ops.embed_attn,
-                       dict(n_heads=2, block_k=2, interpret=True)),
+                       dict(n_heads=2, block_r=8, interpret=True)),
                   argnums=tuple(range(5)))(*diff_args)
     gr = jax.grad(loss(ref.embed_attn_ref, dict(n_heads=2)),
                   argnums=tuple(range(5)))(*diff_args)
+    # relative to each gradient's scale: the entries reach ~80, where float32
+    # summation-order differences alone are ~1e-5
     for a, b in zip(gk, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b, rtol=0,
+                                   atol=5e-6 * np.abs(b).max())
 
 
 # ---------------------------------------------------------------------------
