@@ -149,6 +149,7 @@ def _embed_attn_pallas(h_self, tab, idx, dt, valid, tw, tb, wq, wk, wv, *,
         out_shape=tiling.out_struct((rp, e), jnp.float32, h_self, tab, idx,
                                     dt, valid),
         interpret=interpret,
+        name="embed_attn",
     )(idx_flat, h_self, tiling.slab_view(tab), dt.astype(jnp.float32),
       valid.astype(jnp.int32), tw.reshape(1, d_time), tb.reshape(1, d_time), wq,
       wk[:d_tab], wk[d_tab:], wv[:d_tab], wv[d_tab:])

@@ -108,6 +108,7 @@ def _flash_attn_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((q_block, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attn",
     )(q, k, v)
     return out
 

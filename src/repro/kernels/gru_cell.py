@@ -56,6 +56,7 @@ def _gru_cell_pallas(x, h, w, u, b, *, block_m: int = 128,
         out_specs=pl.BlockSpec((block_m, d), lambda i: (i, 0)),
         out_shape=tiling.out_struct((mm, d), h.dtype, x, h),
         interpret=interpret,
+        name="gru_cell",
     )(x, h, w, u, b)
     return out[:m]
 

@@ -66,6 +66,7 @@ def _link_score_pallas(h_src, h_items, w1, b1, w2, b2, *,
         out_specs=pl.BlockSpec((block_b, block_i), lambda m, n: (m, n)),
         out_shape=jax.ShapeDtypeStruct((bb, ii), jnp.float32),
         interpret=interpret,
+        name="link_score",
     )(h_src, h_items, w1.astype(jnp.float32), b1.astype(jnp.float32),
       w2.astype(jnp.float32), b2.astype(jnp.float32))
     return out[:b, :i]

@@ -111,6 +111,7 @@ def _memory_update_pallas(x, h, w, u, b, delta_mean, scale, gamma, *,
         out_shape=[tiling.out_struct((mm, d), jnp.float32, x, h,
                                      delta_mean, scale)] * 3,
         interpret=interpret,
+        name="memory_update",
     )(x, h, w, u, b.reshape(1, 3 * d), delta_mean, scale,
       jnp.reshape(gamma.astype(jnp.float32), (1, 1)))
     return meas[:m], fused[:m], delta[:m]
@@ -273,6 +274,7 @@ def _memory_update_table_pallas(table, last_t, x, gather_idx, write_idx,
         # 2 = table -> output 0 (in-place table)
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="memory_update_table",
     )(gidx, widx, tiling.slab_view(table), x, ok, w, u, b.reshape(1, 3 * d),
       dmean, sc, jnp.reshape(gamma.astype(jnp.float32), (1, 1)))
     new_tab = tiling.from_slab_view(outs[0], n, d, table.dtype)
@@ -342,6 +344,7 @@ def _pres_predict_pallas(s_prev, delta_mean, scale, *, block_m: int = 256,
         out_shape=tiling.out_struct((mm, d), s_prev.dtype, s_prev,
                                     delta_mean, scale),
         interpret=interpret,
+        name="pres_predict",
     )(s_prev, delta_mean, scale)
     return out[:m]
 

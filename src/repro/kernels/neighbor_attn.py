@@ -59,6 +59,7 @@ def _neighbor_attn_pallas(q, k, v, valid, *, block_m: int = 128,
         out_specs=pl.BlockSpec((block_m, e), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mm, e), q.dtype),
         interpret=interpret,
+        name="neighbor_attn",
     )(q, k, v, valid.astype(jnp.int32))
     return out[:m]
 

@@ -66,6 +66,7 @@ def _pres_filter_pallas(s_prev, s_meas, delta_mean, dt, gamma, *,
             tiling.out_struct((mm, d), jnp.float32, *like),
         ],
         interpret=interpret,
+        name="pres_filter",
     )(s_prev, s_meas, delta_mean, dt,
       jnp.reshape(gamma.astype(jnp.float32), (1, 1)))
     return fused[:m], delta[:m]

@@ -70,6 +70,7 @@ def _ssd_chunk_pallas(q, k, v, lcum, h0, *, interpret: bool = True):
             jax.ShapeDtypeStruct((g, n, p), jnp.float32),
         ],
         interpret=interpret,
+        name="ssd_chunk",
     )(q, k, v, lcum, h0)
     return y, h1
 

@@ -3,11 +3,12 @@
 One schema shared by training and serving: a run-log is a JSONL file
 whose first record is the run manifest (`kind: "manifest"` — provenance,
 config, obs-field schema) followed by per-epoch / per-replay records and
-a closing block the sink writes itself (host spans, the kernel-dispatch
-table from `kernels/ops.py`, and an `end` marker). `benchmarks/common.
-run_metadata` delegates to `run_metadata` here, so committed benchmark
-JSONs and run-logs carry the same provenance fields — including the git
-commit and a config digest, which make a number traceable to a revision.
+a closing block the sink writes itself (host spans and counts, the
+kernel-dispatch table from `kernels/ops.py`, and an `end` marker).
+`benchmarks/common.run_metadata` delegates to `run_metadata` here, so
+committed benchmark JSONs and run-logs carry the same provenance fields —
+including the git commit and a config digest, which make a number
+traceable to a revision.
 
 `tools/inspect_run.py` renders a run-log; `canonical()` strips the
 wall-clock-dependent fields so two runs of the same seed compare equal
@@ -91,9 +92,10 @@ class RunLog:
     The sink never touches device values — callers hand it host scalars /
     lists (the engines' one-fetch-per-epoch flush), so writing a record
     costs a json.dumps and a line append, off the step path entirely.
-    `close()` appends the telemetry epilogue: recorded host spans
-    (obs.trace), the kernel-dispatch table (which execution-policy branch
-    each registered kernel actually took), and an `end` marker."""
+    `close()` appends the telemetry epilogue: recorded host spans and
+    counter totals (obs.trace), the kernel-dispatch table (which
+    execution-policy branch each registered kernel actually took), and an
+    `end` marker."""
 
     def __init__(self, path, *, role: str, cfg=None, argv=None,
                  extra: dict | None = None):
@@ -133,6 +135,9 @@ class RunLog:
         if spans:
             self.write("spans", summary=obs_trace.span_summary(spans),
                        spans=spans)
+        counts = obs_trace.drain_counts()
+        if counts:
+            self.write("counts", counts=counts)
         table = kops.dispatch_log()
         if table:
             self.write("kernel_dispatch", table=table)

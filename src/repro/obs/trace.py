@@ -3,16 +3,24 @@
 Two kinds of spans, matching the two halves of a training/serving step:
 
 * `stage(name)` — a `jax.named_scope` for the jitted pipeline stages
-  (`sample -> route -> memory_update -> embed -> loss -> apply`). Names
-  land in the HLO and show up in `jax.profiler` traces; at runtime the
+  (`memory_update -> embed -> loss -> apply -> maintain_state`). Names
+  land in the HLO op names, so a profiler trace read against the
+  compiled program attributes device time to them; at runtime the
   annotation is free, so stages are always on.
-* `span(name)`  — a host wall-clock span for the non-jitted stages
-  (prefetch waits, event-store window mapping, checkpoint IO, eval).
-  Recording is gated by `enable()`: disabled (the default) a span is a
-  no-op with no timer reads, so instrumented hot paths cost nothing
-  unless a run asked for telemetry. When the `jax.profiler` is active the
-  span additionally emits a `TraceAnnotation`, so host stages line up
-  with device activity in the captured trace.
+* `span(name, **args)` — a host span for the not-jitted stages: the
+  training loop's sampling, step dispatch and logits pull, the serve
+  engine's padding, dispatch and answer pulls, prefetch waits, event-store
+  windowing, checkpoint IO. Recording is gated by `enable()`. Disabled
+  (the default) `span` returns one shared no-op context manager after a
+  single global read: no clock read, no profiler call. Enabled, it opens
+  a `jax.profiler.TraceAnnotation` named "repro." + name, carrying
+  `args` (a step, chunk or kind), so a captured profiler trace holds each
+  host stage on the device trace's own clock; it also appends
+  {name, t0, dur_s, args} to the in-memory list `drain()` returns.
+
+`count(name, n)` adds to a named host counter under the same switch
+(`drain_counts()` returns and clears the totals): the serve engine counts
+the rows it was asked for and the rows it sent after padding.
 
 `StepTraceCapture` wraps a jitted step callable and captures a real
 `jax.profiler` trace for a bounded step window (`--trace-dir` /
@@ -28,9 +36,14 @@ import time
 
 import jax
 
+# every host span's profiler annotation starts with this, so a trace
+# reader can tell the program's spans from anyone else's
+PREFIX = "repro."
+
 _lock = threading.Lock()
 _enabled = False
 _spans: list[dict] = []
+_counts: dict[str, int] = {}
 _t0 = 0.0
 
 
@@ -40,10 +53,12 @@ def stage(name: str):
 
 
 def enable() -> None:
-    """Start recording host spans (timestamps relative to this call)."""
+    """Start recording host spans and counts (span timestamps relative to
+    this call)."""
     global _enabled, _t0
     with _lock:
         _spans.clear()
+        _counts.clear()
         _t0 = time.perf_counter()
         _enabled = True
 
@@ -59,30 +74,68 @@ def enabled() -> bool:
 
 
 def drain() -> list[dict]:
-    """Return and clear the recorded spans ([{name, t0, dur_s}, ...])."""
+    """Return and clear the recorded spans ([{name, t0, dur_s, args}, ...];
+    `args` only where the span was given some)."""
     with _lock:
         out, _spans[:] = list(_spans), []
     return out
 
 
-@contextlib.contextmanager
-def span(name: str):
-    """Host wall-clock span. No-op (no timer reads) unless `enable()`d.
-    Safe from any thread — the prefetch producer records through the same
-    collector as the main thread."""
+def count(name: str, n: int = 1) -> None:
+    """Add `n` to the host counter `name`. No-op unless `enable()`d."""
     if not _enabled:
-        yield
         return
-    start = time.perf_counter()
-    with jax.profiler.TraceAnnotation(name):
-        try:
-            yield
-        finally:
-            dur = time.perf_counter() - start
-            with _lock:
-                if _enabled:
-                    _spans.append({"name": name, "t0": start - _t0,
-                                   "dur_s": dur})
+    with _lock:
+        _counts[name] = _counts.get(name, 0) + int(n)
+
+
+def drain_counts() -> dict[str, int]:
+    """Return and clear the counter totals ({name: total})."""
+    with _lock:
+        out = dict(_counts)
+        _counts.clear()
+    return out
+
+
+class _Span:
+    """An enabled host span: the profiler annotation is opened first and
+    closed last, so it brackets the timed stretch."""
+
+    __slots__ = ("name", "args", "_ann", "_start")
+
+    def __init__(self, name: str, args: dict):
+        self.name, self.args = name, args
+
+    def __enter__(self):
+        self._ann = jax.profiler.TraceAnnotation(PREFIX + self.name,
+                                                 **self.args)
+        self._ann.__enter__()
+        self._start = time.perf_counter()
+
+    def __exit__(self, *exc):
+        end = time.perf_counter()
+        self._ann.__exit__(*exc)
+        rec = {"name": self.name, "t0": self._start - _t0,
+               "dur_s": end - self._start}
+        if self.args:
+            rec["args"] = self.args
+        with _lock:
+            if _enabled:
+                _spans.append(rec)
+        return False
+
+
+# the one object every disabled span returns
+_NOOP = contextlib.nullcontext()
+
+
+def span(name: str, **args):
+    """Host span (see the module docstring). Safe from any thread: the
+    prefetch producer records through the same collector as the main
+    thread."""
+    if not _enabled:
+        return _NOOP
+    return _Span(name, args)
 
 
 def span_summary(spans: list[dict]) -> dict:

@@ -159,35 +159,58 @@ class ServeEngine:
     # host API (micro-batched: pad-to-bucket, split-over-max)
     # ------------------------------------------------------------------ #
 
+    # Host spans (obs.trace, recorded when enabled) tile each call: input
+    # conversion, bucket padding and device puts (`serve.pad`), each jitted
+    # call until it returns (`serve.dispatch`), and pulling answers back or
+    # waiting on the state (`serve.pull`), each with the request's kind and
+    # chunk index. The counters `serve.rows_valid` and `serve.rows_sent`
+    # total the rows asked for and the rows sent after padding.
+
+    @staticmethod
+    def _count_rows(valid: int, sent: int) -> None:
+        obs_trace.count("serve.rows_valid", valid)
+        obs_trace.count("serve.rows_sent", sent)
+
     def ingest(self, src, dst, t, feat=None) -> int:
         """Fold a request of events (chronological *within* the request;
         late relative to already-folded events is fine) into the memory.
         Returns the number of events folded."""
-        n = len(np.asarray(src))
-        for eb in self.batcher.pad_events(src, dst, t, feat):
-            self.state = self._ingest_fn(self.params, self.state, eb)
+        n = len(src)
+        chunks = self.batcher.pad_events(src, dst, t, feat)
+        for i, (lo, hi) in enumerate(self.batcher.chunk_spans(n)):
+            with obs_trace.span("serve.pad", kind="ingest", chunk=i):
+                eb = next(chunks)
+                self._count_rows(hi - lo, eb.size)
+            with obs_trace.span("serve.dispatch", kind="ingest", chunk=i):
+                self.state = self._ingest_fn(self.params, self.state, eb)
         return n
 
     def ingest_batch(self, batch: EventBatch) -> None:
         """Fold an already-padded EventBatch (e.g. a temporal batch from an
         offline replay) without re-bucketing — adds that batch's size to
         the compile-shape set, so live traffic should use `ingest`."""
-        self.state = self._ingest_fn(self.params, self.state, batch)
+        with obs_trace.span("serve.dispatch", kind="ingest", chunk=0):
+            self.state = self._ingest_fn(self.params, self.state, batch)
 
     def query(self, src, dst, t) -> np.ndarray:
         """Link scores for candidate (src, dst) pairs at query times `t`."""
-        src = np.asarray(src, np.int32)
-        dst = np.asarray(dst, np.int32)
-        t = np.asarray(t, np.float32)
+        with obs_trace.span("serve.pad", kind="query"):
+            src = np.asarray(src, np.int32)
+            dst = np.asarray(dst, np.int32)
+            t = np.asarray(t, np.float32)
         n = len(src)
         if n == 0:
             return np.zeros((0,), np.float32)
         out = []
-        for lo, hi in self.batcher.chunk_spans(n):
-            s, d, tt, valid = self.batcher.pad_query(src[lo:hi], dst[lo:hi],
-                                                     t[lo:hi])
-            scores = self._query_fn(self.params, self.state, s, d, tt)
-            out.append(np.asarray(scores)[:valid])
+        for i, (lo, hi) in enumerate(self.batcher.chunk_spans(n)):
+            with obs_trace.span("serve.pad", kind="query", chunk=i):
+                s, d, tt, valid = self.batcher.pad_query(
+                    src[lo:hi], dst[lo:hi], t[lo:hi])
+                self._count_rows(valid, s.shape[0])
+            with obs_trace.span("serve.dispatch", kind="query", chunk=i):
+                scores = self._query_fn(self.params, self.state, s, d, tt)
+            with obs_trace.span("serve.pull", kind="query", chunk=i):
+                out.append(np.asarray(scores)[:valid])
         return np.concatenate(out)
 
     def recommend_topk(self, src, t, k: int):
@@ -196,20 +219,24 @@ class ServeEngine:
         if self.item_range is None:
             raise ValueError("recommend_topk needs the engine constructed "
                              "with item_range=(item_lo, item_hi)")
-        src = np.asarray(src, np.int32)
-        t = np.asarray(t, np.float32)
-        n = len(src)
         n_items = self.item_range[1] - self.item_range[0]
         if not 0 < k <= n_items:
             raise ValueError(f"k must be in [1, {n_items}], got {k}")
-        fn = self._get_topk_fn(k)
+        with obs_trace.span("serve.pad", kind="topk"):
+            src = np.asarray(src, np.int32)
+            t = np.asarray(t, np.float32)
+            fn = self._get_topk_fn(k)
         vals_out, ids_out = [], []
-        for lo, hi in self.batcher.chunk_spans(n):
-            s, _, tt, valid = self.batcher.pad_query(
-                src[lo:hi], np.zeros(hi - lo, np.int32), t[lo:hi])
-            vals, ids = fn(self.params, self.state, s, tt)
-            vals_out.append(np.asarray(vals)[:valid])
-            ids_out.append(np.asarray(ids)[:valid])
+        for i, (lo, hi) in enumerate(self.batcher.chunk_spans(len(src))):
+            with obs_trace.span("serve.pad", kind="topk", chunk=i):
+                s, _, tt, valid = self.batcher.pad_query(
+                    src[lo:hi], np.zeros(hi - lo, np.int32), t[lo:hi])
+                self._count_rows(valid, s.shape[0])
+            with obs_trace.span("serve.dispatch", kind="topk", chunk=i):
+                vals, ids = fn(self.params, self.state, s, tt)
+            with obs_trace.span("serve.pull", kind="topk", chunk=i):
+                vals_out.append(np.asarray(vals)[:valid])
+                ids_out.append(np.asarray(ids)[:valid])
         return np.concatenate(vals_out), np.concatenate(ids_out)
 
     # ------------------------------------------------------------------ #
@@ -247,4 +274,5 @@ class ServeEngine:
         self.block_until_ready()
 
     def block_until_ready(self) -> None:
-        jax.block_until_ready(self.state)
+        with obs_trace.span("serve.pull", kind="state"):
+            jax.block_until_ready(self.state)

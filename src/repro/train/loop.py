@@ -203,7 +203,15 @@ def maintain_state(cfg: MDGNNConfig, params, state2, aux,
     """Non-differentiable post-step state maintenance: PRES tracker update,
     neighbour ring buffers, APAN mailbox. Shared by the sequential and the
     pipelined train steps. With cfg.n_shards > 1 every table updates
-    owner-locally on its shard (repro.train.routing)."""
+    owner-locally on its shard (repro.train.routing). Runs under the
+    `maintain_state` stage scope, so its device time is attributable in a
+    profiler trace."""
+    with obs_trace.stage("maintain_state"):
+        return _maintain_state(cfg, params, state2, aux, prev_batch)
+
+
+def _maintain_state(cfg: MDGNNConfig, params, state2, aux,
+                    prev_batch: EventBatch):
     if cfg.n_shards > 1:
         from repro.train import routing
         return routing.sharded_maintain_state(cfg, params, state2, aux,
@@ -432,34 +440,43 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
     (`EventStream.prefetch_batches`) — the driver consumes it pairwise.
     Loss scalars stay on device until epoch end (no per-step `float(...)`
     sync); logits are pulled to numpy as they arrive so device memory stays
-    bounded at one step's worth."""
+    bounded at one step's worth.
+
+    Host spans (obs.trace, recorded when enabled) tile each step: the
+    negative sampling (`train.sample`), the step call until it returns
+    (`train.dispatch`), the logits pull (`train.pull`), each carrying the
+    step index; then the epoch-end work (`train.epoch_end`)."""
     t0 = time.perf_counter()
     losses, pos_all, neg_all = [], [], []
     obs = obs_metrics.EpochObs()
     it = iter(batches)
     try:
         prev_batch = next(it)
-        for batch in it:
-            key, sub = jax.random.split(key)
-            neg = sample_negatives(sub, batch, *dst_range)
-            params, opt_state, state, m = train_step(params, opt_state, state,
-                                                     prev_batch, batch, neg)
-            losses.append(m["loss"])                   # device scalar
-            pos_all.append(np.asarray(m["logit_p"]))
-            neg_all.append(np.asarray(m["logit_n"]))
-            obs.step(m)                                # device values only
+        for i, batch in enumerate(it):
+            with obs_trace.span("train.sample", step=i):
+                key, sub = jax.random.split(key)
+                neg = sample_negatives(sub, batch, *dst_range)
+            with obs_trace.span("train.dispatch", step=i):
+                params, opt_state, state, m = train_step(
+                    params, opt_state, state, prev_batch, batch, neg)
+            with obs_trace.span("train.pull", step=i):
+                losses.append(m["loss"])               # device scalar
+                pos_all.append(np.asarray(m["logit_p"]))
+                neg_all.append(np.asarray(m["logit_n"]))
+                obs.step(m)                            # device values only
             prev_batch = batch
     finally:
         # stop a PrefetchIterator's producer thread if the epoch aborts
         close = getattr(it, "close", None)
         if close is not None:
             close()
-    losses = [float(x) for x in losses]                # one host sync
-    route_overflow, obs_out = obs.finish()             # one more (batched)
-    ap = metrics_lib.average_precision(np.concatenate(pos_all),
-                                       np.concatenate(neg_all))
-    aps = [metrics_lib.average_precision(p, n) for p, n in zip(pos_all, neg_all)] \
-        if collect_logits else []
+    with obs_trace.span("train.epoch_end"):
+        losses = [float(x) for x in losses]            # one host sync
+        route_overflow, obs_out = obs.finish()         # one more (batched)
+        ap = metrics_lib.average_precision(np.concatenate(pos_all),
+                                           np.concatenate(neg_all))
+        aps = [metrics_lib.average_precision(p, n)
+               for p, n in zip(pos_all, neg_all)] if collect_logits else []
     dt = time.perf_counter() - t0
     return params, opt_state, state, EpochResult(
         ap, float(np.mean(losses)), dt, aps,
@@ -467,23 +484,29 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
 
 
 def evaluate(params, state, batches, cfg: MDGNNConfig, eval_step, key, dst_range):
-    """Evaluation pass; `batches` may be a list or a (prefetching) iterator."""
+    """Evaluation pass; `batches` may be a list or a (prefetching) iterator.
+    Host spans as in `run_epoch`."""
     pos_all, neg_all = [], []
     it = iter(batches)
     try:
         prev_batch = next(it)
-        for batch in it:
-            key, sub = jax.random.split(key)
-            neg = sample_negatives(sub, batch, *dst_range)
-            state, lp, ln = eval_step(params, state, prev_batch, batch, neg)
-            pos_all.append(np.asarray(lp))
-            neg_all.append(np.asarray(ln))
+        for i, batch in enumerate(it):
+            with obs_trace.span("train.sample", step=i):
+                key, sub = jax.random.split(key)
+                neg = sample_negatives(sub, batch, *dst_range)
+            with obs_trace.span("train.dispatch", step=i):
+                state, lp, ln = eval_step(params, state, prev_batch, batch,
+                                          neg)
+            with obs_trace.span("train.pull", step=i):
+                pos_all.append(np.asarray(lp))
+                neg_all.append(np.asarray(ln))
             prev_batch = batch
     finally:
         close = getattr(it, "close", None)
         if close is not None:
             close()
-    ap = metrics_lib.average_precision(np.concatenate(pos_all),
-                                       np.concatenate(neg_all))
-    auc = metrics_lib.roc_auc(np.concatenate(pos_all), np.concatenate(neg_all))
+    with obs_trace.span("train.epoch_end"):
+        pos, neg = np.concatenate(pos_all), np.concatenate(neg_all)
+        ap = metrics_lib.average_precision(pos, neg)
+        auc = metrics_lib.roc_auc(pos, neg)
     return state, ap, auc

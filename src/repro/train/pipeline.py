@@ -274,7 +274,9 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
     Per-step metrics stay on device; the single host sync happens at epoch
     end (the sequential loop also defers its loss syncs to epoch end, but
     still pulls each step's logits — and the scan engine, repro.train.scan,
-    amortizes even that to once per macro-batch)."""
+    amortizes even that to once per macro-batch). Host spans carry the
+    sequential loop's names; `train.pull` here only collects the step's
+    device outputs, which `train.epoch_end` pulls."""
     if cfg.pipeline_depth == 0:
         # loop.run_epoch consumes lists and lazy iterators alike
         return loop_lib.run_epoch(params, opt_state, state, batches, cfg,
@@ -294,15 +296,20 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
     it = iter(batches)
     try:
         prev_batch = next(it)
-        for batch in it:
-            key, sub = jax.random.split(key)
-            neg = sample_negatives(sub, batch, *dst_range)
-            params, opt_state, state, pstate, m = train_step(
-                params, opt_state, state, pstate, prev_batch, batch, neg)
-            losses.append(m["loss"])
-            pos_all.append(m["logit_p"])
-            neg_all.append(m["logit_n"])
-            obs.step(m)
+        for i, batch in enumerate(it):
+            with obs_trace.span("train.sample", step=i):
+                key, sub = jax.random.split(key)
+                neg = sample_negatives(sub, batch, *dst_range)
+            with obs_trace.span("train.dispatch", step=i):
+                params, opt_state, state, pstate, m = train_step(
+                    params, opt_state, state, pstate, prev_batch, batch, neg)
+            # the step's outputs stay on device: their pull is deferred to
+            # the epoch end
+            with obs_trace.span("train.pull", step=i):
+                losses.append(m["loss"])
+                pos_all.append(m["logit_p"])
+                neg_all.append(m["logit_n"])
+                obs.step(m)
             prev_batch = batch
     finally:
         # stop a PrefetchIterator's producer thread if the epoch aborts
@@ -310,14 +317,15 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
         if close is not None:
             close()
     # one host sync for the whole epoch
-    losses = [float(x) for x in losses]
-    pos_all = [np.asarray(x) for x in pos_all]
-    neg_all = [np.asarray(x) for x in neg_all]
-    route_overflow, obs_out = obs.finish()
-    ap = metrics_lib.average_precision(np.concatenate(pos_all),
-                                       np.concatenate(neg_all))
-    aps = [metrics_lib.average_precision(p, n)
-           for p, n in zip(pos_all, neg_all)] if collect_logits else []
+    with obs_trace.span("train.epoch_end"):
+        losses = [float(x) for x in losses]
+        pos_all = [np.asarray(x) for x in pos_all]
+        neg_all = [np.asarray(x) for x in neg_all]
+        route_overflow, obs_out = obs.finish()
+        ap = metrics_lib.average_precision(np.concatenate(pos_all),
+                                           np.concatenate(neg_all))
+        aps = [metrics_lib.average_precision(p, n)
+               for p, n in zip(pos_all, neg_all)] if collect_logits else []
     dt = time.perf_counter() - t0
     return params, opt_state, state, loop_lib.EpochResult(
         ap, float(np.mean(losses)), dt, aps,

@@ -12,13 +12,18 @@
   rejection of malformed files, and canonical() log equality for two
   runs of the same seeded computation;
 * tools/inspect_run.py rendering a run-log into the report sections the
-  acceptance criteria name.
+  acceptance criteria name;
+* host spans and counters: free when disabled, a `repro.`-prefixed
+  profiler annotation when enabled, tiling each training step and each
+  serve call; the `maintain_state` stage scope in the compiled step.
 """
 from __future__ import annotations
 
+import contextlib
 import importlib.util
 import json
 import pathlib
+import re
 
 import numpy as np
 import pytest
@@ -408,6 +413,151 @@ def test_spans_disabled_by_default_and_drain():
     summ = obs_trace.span_summary(spans)
     assert summ["real_stage"]["count"] == 1
     assert obs_trace.drain() == []          # drained means drained
+
+
+class _Annotations:
+    """Stands in for jax.profiler.TraceAnnotation and records its uses."""
+
+    def __init__(self):
+        self.opened = []
+
+    def __call__(self, name, **args):
+        self.opened.append((name, args))
+        return contextlib.nullcontext()
+
+
+def test_disabled_span_reads_no_clock_and_opens_no_annotation(monkeypatch):
+    import time
+    reads, ann = [], _Annotations()
+    real = time.perf_counter
+    monkeypatch.setattr(time, "perf_counter",
+                        lambda: reads.append(1) or real())
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    assert not obs_trace.enabled()
+    first = obs_trace.span("train.sample", step=0)
+    with first:
+        with obs_trace.span("serve.pad", kind="query", chunk=1):
+            obs_trace.count("serve.rows_valid", 5)
+    # one shared no-op object: no generator, no timer, no annotation
+    assert obs_trace.span("other") is first
+    assert reads == [] and ann.opened == []
+    assert obs_trace.drain() == [] and obs_trace.drain_counts() == {}
+
+
+def test_enabled_span_annotates_with_prefix_and_args_and_counts(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    obs_trace.enable()
+    try:
+        with obs_trace.span("train.dispatch", step=3):
+            pass
+        with obs_trace.span("prefetch_wait"):
+            pass
+        obs_trace.count("serve.rows_valid", 690)
+        obs_trace.count("serve.rows_sent", 1024)
+        obs_trace.count("serve.rows_valid", 10)
+        spans, counts = obs_trace.drain(), obs_trace.drain_counts()
+    finally:
+        obs_trace.disable()
+    assert ann.opened == [("repro.train.dispatch", {"step": 3}),
+                          ("repro.prefetch_wait", {})]
+    assert [(s["name"], s.get("args")) for s in spans] == [
+        ("train.dispatch", {"step": 3}), ("prefetch_wait", None)]
+    assert counts == {"serve.rows_valid": 700, "serve.rows_sent": 1024}
+    assert obs_trace.drain_counts() == {}     # drained means drained
+
+
+def test_runlog_writes_counts_beside_spans(tmp_path):
+    obs_trace.enable()
+    try:
+        log = sink.RunLog(tmp_path / "r.jsonl", role="serve")
+        with obs_trace.span("serve.pull", kind="state"):
+            pass
+        obs_trace.count("serve.rows_sent", 16)
+        log.close()
+    finally:
+        obs_trace.disable()
+    recs = {r["kind"]: r for r in sink.read_runlog(tmp_path / "r.jsonl")}
+    assert recs["counts"]["counts"] == {"serve.rows_sent": 16}
+    assert recs["spans"]["spans"][0]["args"] == {"kind": "state"}
+
+
+def _epoch_spans(stream, spec, runner):
+    dst = (spec.n_users, spec.n_users + spec.n_items)
+    depth = 1 if runner == "pipelined" else 0
+    cfg = _cfg(stream, pipeline_depth=depth, obs_metrics=False)
+    params, opt, opt_state, state = _init(cfg)
+    batches = stream.temporal_batches(100)
+    obs_trace.enable()
+    try:
+        if runner == "evaluate":
+            loop.evaluate(params, state, batches, cfg,
+                          loop.make_eval_step(cfg), jax.random.PRNGKey(0),
+                          dst)
+        else:
+            pipeline.run_epoch(params, opt_state, state, batches, cfg,
+                               pipeline.make_train_step(cfg, opt),
+                               jax.random.PRNGKey(0), dst)
+        return obs_trace.drain(), len(batches) - 1
+    finally:
+        obs_trace.disable()
+
+
+@pytest.mark.parametrize("runner", ["sequential", "pipelined", "evaluate"])
+def test_epoch_spans_tile_each_step(tiny_stream, tiny_spec, runner):
+    """Each step of an epoch is one sample, one dispatch and one pull span,
+    in that order and carrying the step index; the epoch ends in one
+    epoch_end span."""
+    spans, n_steps = _epoch_spans(tiny_stream, tiny_spec, runner)
+    names = [s["name"] for s in spans]
+    assert names == ["train.sample", "train.dispatch",
+                     "train.pull"] * n_steps + ["train.epoch_end"]
+    assert [s["args"]["step"] for s in spans[:-1]] == [
+        i for i in range(n_steps) for _ in range(3)]
+    ends = [s["t0"] + s["dur_s"] for s in spans]
+    assert all(b["t0"] >= a for a, b in zip(ends, spans[1:]))
+
+
+def test_train_step_hlo_scopes_maintain_state(tiny_stream):
+    """The state maintenance after the optimizer update runs under its own
+    stage scope, so its device ops carry the name in their op_name."""
+    cfg = _cfg(tiny_stream, obs_metrics=False)
+    params, opt, opt_state, state = _init(cfg)
+    b = tiny_stream.temporal_batches(100)
+    neg = sample_negatives(jax.random.PRNGKey(0), b[1], 50, 80)
+    text = loop.make_train_step(cfg, opt).lower(
+        params, opt_state, state, b[0], b[1], neg).compile().as_text()
+    ops = re.findall(r'op_name="([^"]*)"', text)
+    scoped = [o for o in ops if "/maintain_state/" in o]
+    assert scoped and not any("maintain_state" in o and "embed" in o
+                              for o in ops)
+
+
+def test_engine_counts_valid_and_sent_rows(tiny_stream, tiny_spec):
+    """A query of 1,380 pairs goes out as two chunks of the 1,024 bucket:
+    1,380 rows asked for, 2,048 sent, one dispatch span per chunk."""
+    cfg = _cfg(tiny_stream, obs_metrics=False)
+    params, _, _, state = _init(cfg)
+    eng = ServeEngine(cfg, params, state,
+                      batcher=MicroBatcher(d_edge=tiny_stream.feat_dim))
+    rng = np.random.default_rng(0)
+    n = 1380
+    src = rng.integers(0, tiny_spec.n_users, n)
+    dst = rng.integers(tiny_spec.n_users,
+                       tiny_spec.n_users + tiny_spec.n_items, n)
+    obs_trace.enable()
+    try:
+        scores = eng.query(src, dst, np.full(n, 1.0))
+        spans, counts = obs_trace.drain(), obs_trace.drain_counts()
+    finally:
+        obs_trace.disable()
+    assert scores.shape == (n,)
+    assert counts == {"serve.rows_valid": 1380, "serve.rows_sent": 2048}
+    dispatch = [s["args"] for s in spans if s["name"] == "serve.dispatch"]
+    assert dispatch == [
+        {"kind": "query", "chunk": 0}, {"kind": "query", "chunk": 1}]
+    assert [s["name"] for s in spans] == (
+        ["serve.pad"] + ["serve.pad", "serve.dispatch", "serve.pull"] * 2)
 
 
 # ---------------------------------------------------------------------------

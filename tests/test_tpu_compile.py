@@ -7,8 +7,8 @@ split a tile, layouts it cannot map — so every kernel the training and
 serving path dispatches is compiled here at the widths of the paper's
 model (`configs/tgn_pres.py::CONFIG` on the JODIE-Wikipedia node table)
 and of `PRODUCTION`, and the compiled text must hold the kernel
-(`tpu_custom_call`). Nothing runs, so this says nothing about results or
-speed.
+(`tpu_custom_call`), named after the kernel's registry name. Nothing
+runs, so this says nothing about results or speed.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and the test workers all
@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,4 +105,17 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, widths):
     fn, args = _case(kernel, WIDTHS[widths], spec)
     compiled = jax.jit(lambda *a: fn(*a, interpret=False)).lower(
         *args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's custom call is named after its registry name, so a
+    # profiler trace's op events find it by that name
+    from bench.lib import tracereduce
+    calls = [{"name": m.group(1), "dur_ns": 1.0}
+             for m in _CUSTOM_CALL.finditer(text)]
+    assert calls and all(re.fullmatch(re.escape(kernel) + r"(\.\d+)?",
+                                      c["name"]) for c in calls)
+    assert tracereduce.kernel_ns(calls, kernel) == (len(calls), len(calls))
+
+
+_CUSTOM_CALL = re.compile(r"^\s*(?:ROOT\s+)?%?([^ =]+) = .*"
+                          r'custom_call_target="tpu_custom_call"', re.M)
