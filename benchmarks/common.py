@@ -106,8 +106,7 @@ def train_run(stream: EventStream, spec, *, variant="tgn", use_pres=False,
     # compile (first step) timed separately so epoch_seconds is steady-state;
     # the steps donate their opt/model state, so warm-up runs on copies
     t0 = time.perf_counter()
-    from repro.graph.negatives import sample_negatives
-    neg = sample_negatives(key, warm[1], *dst_range)
+    from repro.graph.negatives import NegativeDraw, sample_negatives
     if engine is not None:
         # a full-chunk macro when the stream has one (the tail-size compile
         # lands in epoch 0, which the figs drop as warm-up)
@@ -119,10 +118,11 @@ def train_run(stream: EventStream, spec, *, variant="tgn", use_pres=False,
     elif pipeline_depth:
         pstate = pipeline.PipelineState.init(state["memory"])
         step(_copy_tree(params), _copy_tree(opt_state), _copy_tree(state),
-             pstate, warm[0], warm[1], neg)
-    else:
+             pstate, warm[0], warm[1],
+             sample_negatives(key, warm[1], *dst_range))
+    else:   # the sequential loop draws its negatives inside the step
         step(_copy_tree(params), _copy_tree(opt_state), _copy_tree(state),
-             warm[0], warm[1], neg)
+             warm[0], warm[1], NegativeDraw.start(key, dst_range))
     compile_s = time.perf_counter() - t0
 
     aps, losses, secs, per_batch = [], [], [], []

@@ -21,7 +21,7 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.train import annotate
 from repro.graph.events import EventBatch, EventStream
-from repro.graph.negatives import sample_negatives
+from repro.graph.negatives import NegativeDraw, split_and_sample
 from repro.models import mdgnn, modules
 from repro.models.mdgnn import MDGNNConfig, MemoryState
 from repro.utils import metrics as metrics_lib
@@ -259,6 +259,17 @@ def _obs_step_stats(params, cfg: MDGNNConfig, info, fused, loss, pen,
         events=jnp.sum(pos.mask.astype(jnp.float32))))
 
 
+def step_negatives(neg, pos: EventBatch):
+    """A step's negatives from either form of its negatives argument,
+    chosen by pytree type at trace time (each form compiles once): an
+    `EventBatch` is used as given; a `NegativeDraw` is split and sampled
+    here, inside the compiled step, in the host loop's key order.
+    Returns (negatives, next key — None for an `EventBatch`)."""
+    if isinstance(neg, NegativeDraw):
+        return split_and_sample(neg.key, pos, neg.dst[0], neg.dst[1])
+    return neg, None
+
+
 def make_step_body(cfg: MDGNNConfig, opt, gru_fn=None):
     """Un-jitted train-step body, shared by every trainer that runs the
     lag-one recurrence: the sequential jitted step below, the scan-compiled
@@ -267,7 +278,10 @@ def make_step_body(cfg: MDGNNConfig, opt, gru_fn=None):
     traces it with the annotate hooks installed).
 
     Signature: (params, opt_state, state, prev_batch, pos, neg)
-            -> (params, opt_state, state, metrics)."""
+            -> (params, opt_state, state, metrics).
+    `neg` is an `EventBatch` of negatives or a `NegativeDraw`, which the
+    step samples from itself (`step_negatives`); it then returns the next
+    key as metrics["neg_key"]."""
     if gru_fn is None:
         gru_fn = modules.kernel_memory_cell(cfg)
 
@@ -315,6 +329,7 @@ def make_step_body(cfg: MDGNNConfig, opt, gru_fn=None):
         return loss, (state2, aux)
 
     def train_step(params, opt_state, state, prev_batch, pos, neg):
+        neg, next_key = step_negatives(neg, pos)
         (loss, (state2, aux)), grads = jax.value_and_grad(
             loss_and_state, has_aux=True)(params, state, prev_batch, pos, neg)
         with obs_trace.stage("apply"):
@@ -332,6 +347,8 @@ def make_step_body(cfg: MDGNNConfig, opt, gru_fn=None):
         for k in ("obs", "route_overflow_shards"):
             if k in aux:
                 metrics[k] = aux[k]
+        if next_key is not None:
+            metrics["neg_key"] = next_key
         return params, opt_state, state2, metrics
 
     return train_step
@@ -356,9 +373,10 @@ def make_train_step(cfg: MDGNNConfig, opt, gru_fn=None):
     they passed in — only the returned ones.
 
     With cfg.n_shards > 1 the returned step additionally replicates the
-    per-step host inputs (batches, negatives) onto the mesh before the
-    jitted call — the carried params/opt_state/state are expected already
-    placed by routing.replicate/shard_state (docs/DISTRIBUTED.md)."""
+    per-step host inputs (batches, negatives or their draw) onto the mesh
+    before the jitted call — the carried params/opt_state/state are
+    expected already placed by routing.replicate/shard_state
+    (docs/DISTRIBUTED.md)."""
     step = jax.jit(make_step_body(cfg, opt, gru_fn=gru_fn),
                    donate_argnums=(1, 2))
     return _replicating_inputs(cfg, step, n_carry=3)
@@ -381,9 +399,18 @@ def _replicating_inputs(cfg: MDGNNConfig, step, n_carry: int):
 
 
 def make_eval_step(cfg: MDGNNConfig):
+    """Jitted fold-then-score step: (params, state, prev_batch, pos, neg)
+    -> (state, logit_p, logit_n). Given a `NegativeDraw` as `neg` it samples
+    in the step, like the train step, and returns the next key as a fourth
+    output."""
     gru_fn = modules.kernel_memory_cell(cfg)
 
     def eval_step(params, state, prev_batch, pos, neg):
+        neg, next_key = step_negatives(neg, pos)
+        out = fold_and_score(params, state, prev_batch, pos, neg)
+        return out if next_key is None else out + (next_key,)
+
+    def fold_and_score(params, state, prev_batch, pos, neg):
         mem2, _, _, _ = memory_and_pres(params, cfg, state, prev_batch,
                                         gru_fn=gru_fn)
         state2 = dict(state, memory=mem2)
@@ -442,23 +469,29 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
     sync); logits are pulled to numpy as they arrive so device memory stays
     bounded at one step's worth.
 
+    The negatives are drawn inside the compiled step (`NegativeDraw`): the
+    host forwards the key the previous step returned and the destination
+    bounds, put on the device once per epoch, so no eager program runs per
+    step besides the step itself.
+
     Host spans (obs.trace, recorded when enabled) tile each step: the
-    negative sampling (`train.sample`), the step call until it returns
+    draw's forwarding (`train.sample`), the step call until it returns
     (`train.dispatch`), the logits pull (`train.pull`), each carrying the
     step index; then the epoch-end work (`train.epoch_end`)."""
     t0 = time.perf_counter()
     losses, pos_all, neg_all = [], [], []
     obs = obs_metrics.EpochObs()
+    draw = NegativeDraw.start(key, dst_range)
     it = iter(batches)
     try:
         prev_batch = next(it)
         for i, batch in enumerate(it):
             with obs_trace.span("train.sample", step=i):
-                key, sub = jax.random.split(key)
-                neg = sample_negatives(sub, batch, *dst_range)
+                draw = dataclasses.replace(draw, key=key)
             with obs_trace.span("train.dispatch", step=i):
                 params, opt_state, state, m = train_step(
-                    params, opt_state, state, prev_batch, batch, neg)
+                    params, opt_state, state, prev_batch, batch, draw)
+                key = m.pop("neg_key")
             with obs_trace.span("train.pull", step=i):
                 losses.append(m["loss"])               # device scalar
                 pos_all.append(np.asarray(m["logit_p"]))
@@ -485,18 +518,19 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
 
 def evaluate(params, state, batches, cfg: MDGNNConfig, eval_step, key, dst_range):
     """Evaluation pass; `batches` may be a list or a (prefetching) iterator.
-    Host spans as in `run_epoch`."""
+    Negatives are drawn inside the step and host spans tile each step, as
+    in `run_epoch`."""
     pos_all, neg_all = [], []
+    draw = NegativeDraw.start(key, dst_range)
     it = iter(batches)
     try:
         prev_batch = next(it)
         for i, batch in enumerate(it):
             with obs_trace.span("train.sample", step=i):
-                key, sub = jax.random.split(key)
-                neg = sample_negatives(sub, batch, *dst_range)
+                draw = dataclasses.replace(draw, key=key)
             with obs_trace.span("train.dispatch", step=i):
-                state, lp, ln = eval_step(params, state, prev_batch, batch,
-                                          neg)
+                state, lp, ln, key = eval_step(params, state, prev_batch,
+                                               batch, draw)
             with obs_trace.span("train.pull", step=i):
                 pos_all.append(np.asarray(lp))
                 neg_all.append(np.asarray(ln))

@@ -1,11 +1,11 @@
 """Scan-compiled macro-batch training (docs/SCAN.md).
 
 The sequential loop (repro.train.loop) dispatches one jitted step per
-temporal batch from Python: per-step dispatch latency, a host-side PRNG
-split for the negatives, and a host transfer of the step's logits. PRES
-exists to raise the effective temporal batch size, so in the small-batch
-regimes the paper sweeps (Fig. 3/5) that fixed per-batch tax dominates the
-actual compute. This module compiles the lag-one recurrence itself:
+temporal batch from Python: per-step dispatch latency and a host transfer
+of the step's logits. PRES exists to raise the effective temporal batch
+size, so in the small-batch regimes the paper sweeps (Fig. 3/5) that fixed
+per-batch tax dominates the actual compute. This module compiles the
+lag-one recurrence itself:
 
 * T consecutive temporal batches are stacked into one (T+1, b, ...)
   *macro-batch* (`events.stack_batches` / `events.iter_macro_batches`,
@@ -14,9 +14,9 @@ actual compute. This module compiles the lag-one recurrence itself:
 * ONE jitted call runs the existing train-step body
   (`loop.make_step_body` — kernel routing, PRES fusion and all) under
   `jax.lax.scan`, carry = (params, opt_state, full model state, PRNG key);
-* negative sampling happens INSIDE the step (`sample_negatives_in`,
-  driven by the carried key — split in exactly the host loop's order, so
-  the negatives are bit-identical to the sequential loop's);
+* negative sampling happens INSIDE the step (`split_and_sample`, driven
+  by the carried key — the helper the sequential step's in-step draw uses
+  too, so the negatives are bit-identical to the sequential loop's);
 * per-step metrics come back stacked on device: one dispatch and one host
   transfer per T batches instead of per batch;
 * the carry's big buffers (memory table, neighbour ring buffers, PRES
@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.graph.events import EventBatch, iter_macro_batches
-from repro.graph.negatives import sample_negatives_in
+from repro.graph.negatives import split_and_sample
 from repro.models.mdgnn import MDGNNConfig
 from repro.obs import metrics as obs_metrics
 from repro.train import loop as loop_lib
@@ -82,8 +82,7 @@ def make_macro_step(cfg: MDGNNConfig, opt, dst_range, gru_fn=None):
         def step(carry, xs):
             params, opt_state, state, key = carry
             prev_batch, pos = xs
-            key, sub = jax.random.split(key)      # same order as the host loop
-            neg = sample_negatives_in(sub, pos, dst_lo, dst_hi)
+            neg, key = split_and_sample(key, pos, dst_lo, dst_hi)
             params, opt_state, state, m = body(params, opt_state, state,
                                                prev_batch, pos, neg)
             return (params, opt_state, state, key), m

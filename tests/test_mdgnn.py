@@ -11,7 +11,7 @@ import jax.numpy as jnp
 
 from repro.core import batching
 from repro.graph.events import EventBatch
-from repro.graph.negatives import sample_negatives
+from repro.graph.negatives import NegativeDraw, sample_negatives
 from repro.models import mdgnn
 from repro.models.mdgnn import MDGNNConfig
 from repro.optim import optimizers
@@ -245,3 +245,111 @@ def test_kernel_routed_train_step_matches_jnp():
                           jax.tree.map(jnp.copy, state), prev, pos, neg)
         outs.append(float(m["loss"]))
     np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Negatives drawn inside the step
+# ---------------------------------------------------------------------------
+
+
+def _stream_cfg(stream, **kw):
+    return MDGNNConfig(variant="tgn", n_nodes=stream.num_nodes,
+                       d_edge=stream.feat_dim, d_mem=16, d_msg=16, d_time=8,
+                       d_embed=16, n_neighbors=4, use_pres=True, **kw)
+
+
+def _assert_trees_equal(a, b):
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("kw", [{}, dict(use_kernels=True, dedup_embed=True)])
+def test_in_step_draw_matches_host_sampled_negatives(tiny_stream, tiny_spec,
+                                                     kw):
+    """`run_epoch` draws each step's negatives inside the compiled step; a
+    hand-written loop that samples them on the host from iterated
+    `jax.random.split` and passes them as an EventBatch must give the same
+    params, node state (memory, ring, PRES trackers), logits and loss."""
+    cfg = _stream_cfg(tiny_stream, **kw)
+    batches = tiny_stream.temporal_batches(100)[:5]
+    dst = (tiny_spec.n_users, tiny_spec.n_users + tiny_spec.n_items)
+    key = jax.random.PRNGKey(11)
+    params, _ = mdgnn.init_params(jax.random.PRNGKey(0), cfg)
+    opt = optimizers.adamw(1e-3)
+    step = loop.make_train_step(cfg, opt)
+
+    seen = []
+
+    def recording(*args):
+        out = step(*args)
+        seen.append((np.asarray(out[3]["logit_p"]),
+                     np.asarray(out[3]["logit_n"])))
+        return out
+
+    p_in, _, s_in, res = loop.run_epoch(
+        params, opt.init(params), mdgnn.init_state(cfg), batches, cfg,
+        recording, key, dst)
+
+    p, o, s = params, opt.init(params), mdgnn.init_state(cfg)
+    k, host, losses = key, [], []
+    for i in range(1, len(batches)):
+        k, sub = jax.random.split(k)
+        neg = sample_negatives(sub, batches[i], *dst)
+        p, o, s, m = step(p, o, s, batches[i - 1], batches[i], neg)
+        assert "neg_key" not in m
+        host.append((np.asarray(m["logit_p"]), np.asarray(m["logit_n"])))
+        losses.append(float(m["loss"]))
+
+    _assert_trees_equal(p_in, p)
+    _assert_trees_equal(s_in, s)
+    _assert_trees_equal(seen, host)
+    assert res.loss == float(np.mean(losses))
+
+
+def test_in_step_draw_lowers_from_shape_structs(tiny_stream, tiny_spec):
+    """The step lowers from ShapeDtypeStructs of the draw form (how a
+    harness reads its compiled HLO), and hands back the key of iterated
+    `jax.random.split` with four outputs."""
+    cfg = _stream_cfg(tiny_stream)
+    b = tiny_stream.temporal_batches(100)
+    params, _ = mdgnn.init_params(jax.random.PRNGKey(0), cfg)
+    opt = optimizers.adamw(1e-3)
+    key = jax.random.PRNGKey(3)
+    args = (params, opt.init(params), mdgnn.init_state(cfg), b[0], b[1],
+            NegativeDraw.start(key, (tiny_spec.n_users,
+                                     tiny_spec.n_users + tiny_spec.n_items)))
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                          args)
+    step = loop.make_train_step(cfg, opt)
+    assert step.lower(*shapes).compile() is not None
+    out = step(*args)
+    assert len(out) == 4 and "loss" in out[3]
+    np.testing.assert_array_equal(np.asarray(out[3]["neg_key"]),
+                                  np.asarray(jax.random.split(key)[0]))
+
+
+def test_evaluate_in_step_draw_matches_host_sampled(tiny_stream, tiny_spec):
+    """`evaluate` draws its negatives inside the eval step and returns the
+    same AP/AUC and state as a loop that samples them on the host."""
+    from repro.utils import metrics as metrics_lib
+    cfg = _stream_cfg(tiny_stream)
+    batches = tiny_stream.temporal_batches(100)
+    dst = (tiny_spec.n_users, tiny_spec.n_users + tiny_spec.n_items)
+    key = jax.random.PRNGKey(5)
+    params, _ = mdgnn.init_params(jax.random.PRNGKey(0), cfg)
+    eval_step = loop.make_eval_step(cfg)
+    s_in, ap_in, auc_in = loop.evaluate(params, mdgnn.init_state(cfg),
+                                        batches, cfg, eval_step, key, dst)
+
+    s, k, pos_all, neg_all = mdgnn.init_state(cfg), key, [], []
+    for i in range(1, len(batches)):
+        k, sub = jax.random.split(k)
+        neg = sample_negatives(sub, batches[i], *dst)
+        s, lp, ln = eval_step(params, s, batches[i - 1], batches[i], neg)
+        pos_all.append(np.asarray(lp))
+        neg_all.append(np.asarray(ln))
+    pos, neg = np.concatenate(pos_all), np.concatenate(neg_all)
+    _assert_trees_equal(s_in, s)
+    assert ap_in == metrics_lib.average_precision(pos, neg)
+    assert auc_in == metrics_lib.roc_auc(pos, neg)
