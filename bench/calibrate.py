@@ -61,16 +61,17 @@ def read_train(spec, seed, devs, controls, emit):
     key = jax.random.fold_in(train.seed_key(seed), 1)
     dst = (g["n_users"], g["n_users"] + g["n_items"])
     ref = out["ref"]
+    arch = spec["module"]
     for kind, kw in (("control_bf16", {"dtype": jnp.bfloat16}),
                      ("half_batch", {"half_batch": True}),
                      ("ref_default_precision", {"precision": "default"})):
         steps, p_end, s_end = reference.run(
-            train.model_spec(config, traffic), ref["params0"], stream,
+            arch, train.model_spec(config, traffic), ref["params0"], stream,
             traffic["batch_size"], dst, key, traffic["check_steps"], **kw)
         stand_in = {"losses": [r["loss"] for r in steps],
                     "grads": steps[0]["grads"], "params0": ref["params0"],
                     "params_end": p_end, "state_end": s_end}
-        numbers, where = compare.training_numbers(stand_in, ref)
+        numbers, where = compare.training_numbers(stand_in, ref, arch.EXACT)
         emit({"kind": kind, "seed": seed, "numbers": numbers,
               "where": where})
 
@@ -92,7 +93,8 @@ def read_serve(spec, seed, devs, seconds, controls, emit):
                         out["topk_asks"], traffic["topk"], items,
                         dtype=jnp.bfloat16)
     emit({"kind": "control_bf16", "seed": seed,
-          "numbers": serve.serve_numbers(ctrl, out["ref"], traffic["topk"])})
+          "numbers": serve.serve_numbers(ctrl, out["ref"], traffic["topk"],
+                                         spec["module"].EXACT)})
 
 
 def main(argv=None) -> int:

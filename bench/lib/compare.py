@@ -16,8 +16,9 @@ weights, events and negatives. Compared, each as a relative gap:
   max |a - reference| / max(1, max |reference|).
 * `state_mismatch`: the entries of the tables that only copy or count
   (last-update times, neighbour rings and their pointers, the trackers'
-  event counts) that differ from the reference at all; they hold event
-  times, node ids and whole counts, so a sound run reads exactly 0.
+  event counts, and the tables the configuration's module names in
+  `EXACT`) that differ from the reference at all; they hold event times,
+  node ids and whole counts, so a sound run reads exactly 0.
   `state.<table>` and `mismatch.<table>` give each table's reading.
 
 Leaves whose reference gradient is under a thousandth of the median leaf's
@@ -58,14 +59,17 @@ def leaf_gaps(prog, ref, leaves) -> dict:
     return {k: abs(p[k] - r[k]) / max(r[k], med, 1e-30) for k in leaves}
 
 
-# node-state tables whose entries are copied times, node ids or whole counts
+# shared node-state tables whose entries are copied times, node ids or
+# whole counts
 EXACT_TABLES = ("last_update", "nbr", "nbr_t", "ptr", "pres_n")
 
 
-def state_numbers(prog: dict, ref: dict) -> tuple:
+def state_numbers(prog: dict, ref: dict, exact=()) -> tuple:
     """({number: value}, {number: worst table}) for the node state:
     `state_gap` over the float tables, `state_mismatch` over
-    `EXACT_TABLES`, and each table's own reading."""
+    `EXACT_TABLES` and the module's `exact` tables, and each table's own
+    reading."""
+    exact_tables = EXACT_TABLES + tuple(exact)
     out, where = {}, {}
     for k, r in ref.items():
         r = np.asarray(r, np.float64)
@@ -74,20 +78,21 @@ def state_numbers(prog: dict, ref: dict) -> tuple:
         out[f"state.{k}"] = float(np.max(diff)) / max(
             1.0, float(np.max(np.abs(r))))
         out[f"mismatch.{k}"] = float(np.count_nonzero(~(diff == 0)))
-    floats = [k for k in ref if k not in EXACT_TABLES]
+    floats = [k for k in ref if k not in exact_tables]
     where["state_gap"] = max(floats, key=lambda k: out[f"state.{k}"])
     out["state_gap"] = out[f"state.{where['state_gap']}"]
-    exact = [k for k in ref if k in EXACT_TABLES]
+    exact = [k for k in ref if k in exact_tables]
     out["state_mismatch"] = float(sum(out[f"mismatch.{k}"] for k in exact))
     where["state_mismatch"] = ", ".join(
         f"{k} {int(out[f'mismatch.{k}'])}" for k in exact)
     return out, where
 
 
-def training_numbers(prog: dict, ref: dict) -> tuple:
+def training_numbers(prog: dict, ref: dict, exact=()) -> tuple:
     """prog / ref: {"losses": [...], "grads": the first step's gradient
-    tree, "params0", "params_end", "state_end"}. Returns
-    ({name: value}, {name: worst part})."""
+    tree, "params0", "params_end", "state_end"}; `exact`: the module's own
+    tables compared entry for entry. Returns ({name: value},
+    {name: worst part})."""
     leaves = counted_leaves(ref["grads"])
     g_prog = _leaves(prog["grads"])
     g_ref = _leaves(ref["grads"])
@@ -105,7 +110,8 @@ def training_numbers(prog: dict, ref: dict) -> tuple:
         where[name] = max(gaps, key=gaps.get)
         out[name] = gaps[where[name]]
         out[name + ".median"] = float(np.median(list(gaps.values())))
-    state, state_where = state_numbers(prog["state_end"], ref["state_end"])
+    state, state_where = state_numbers(prog["state_end"], ref["state_end"],
+                                       exact)
     out.update(state)
     where.update(state_where)
     if not all(np.isfinite(v) for v in out.values()):

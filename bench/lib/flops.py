@@ -4,7 +4,8 @@
   matrix products counted from the configuration's shapes and times 3 for
   forward and backward (each product's backward is two products of the
   same size). Element-wise work, gathers and sorts are not counted, and
-  nothing recomputed is.
+  nothing recomputed is. The embedding's count comes from the
+  configuration's module (`embed_flops` in `bench/configs/<config>.py`).
 * `embed_attn_cost`, `memory_update_table_cost`: what one forward call of
   each Pallas kernel must compute and move. Bytes count the rows the
   kernel gathers and writes, not the whole node table it takes aliased in
@@ -16,43 +17,33 @@ F32 = 4
 I32 = 4
 
 
-def _matmul(m, k, n) -> float:
+def matmul(m, k, n) -> float:
+    """FLOPs of an (m x k) by (k x n) matrix product."""
     return 2.0 * m * k * n
 
 
 def memory_stage_flops(model: dict, d_edge: int, occurrences: int) -> float:
     d, dm, dt = model["d_mem"], model["d_msg"], model["d_time"]
     gates = 3 if model["memory_cell"] == "gru" else 1
-    msg = (_matmul(occurrences, 2 * d + d_edge + dt, dm)
-           + _matmul(occurrences, dm, dm))
-    cell = _matmul(occurrences, dm, gates * d) + _matmul(occurrences, d,
-                                                         gates * d)
+    msg = (matmul(occurrences, 2 * d + d_edge + dt, dm)
+           + matmul(occurrences, dm, dm))
+    cell = matmul(occurrences, dm, gates * d) + matmul(occurrences, d,
+                                                        gates * d)
     return msg + cell
-
-
-def embed_flops(model: dict, rows: int) -> float:
-    d, dt, e, k = model["d_mem"], model["d_time"], model["d_embed"], \
-        model["n_neighbors"]
-    if model["variant"] == "jodie":
-        return _matmul(rows, d, e)
-    q = _matmul(rows, d, e)
-    kv = 2 * _matmul(rows * k, d + dt, e)
-    attn = 2 * 2.0 * rows * k * e          # scores and weighted sum
-    out = _matmul(rows, e + d, e)
-    return q + kv + attn + out
 
 
 def decoder_flops(model: dict, pairs: int) -> float:
     e = model["d_embed"]
-    return _matmul(pairs, 2 * e, e) + _matmul(pairs, e, 1)
+    return matmul(pairs, 2 * e, e) + matmul(pairs, e, 1)
 
 
-def train_step_flops(model: dict, d_edge: int, batch: int) -> float:
+def train_step_flops(model: dict, d_edge: int, batch: int, arch) -> float:
     """Model FLOPs of one lag-one step on batches of `batch` events with
     one negative each: the memory stage over 2 * batch endpoint
-    occurrences, embeddings of 4 * batch rows, 2 * batch scored pairs."""
+    occurrences, embeddings of 4 * batch rows (`arch`: the configuration's
+    module), 2 * batch scored pairs."""
     fwd = (memory_stage_flops(model, d_edge, 2 * batch)
-           + embed_flops(model, 4 * batch)
+           + arch.embed_flops(model, 4 * batch)
            + decoder_flops(model, 2 * batch))
     return 3.0 * fwd
 
@@ -63,7 +54,7 @@ def embed_attn_cost(model: dict, rows: int) -> tuple:
     memory rows)."""
     d, dt, e, k = model["d_mem"], model["d_time"], model["d_embed"], \
         model["n_neighbors"]
-    flops = (_matmul(rows, d, e) + 2 * _matmul(rows * k, d + dt, e)
+    flops = (matmul(rows, d, e) + 2 * matmul(rows * k, d + dt, e)
              + 2 * 2.0 * rows * k * e)
     moved = (rows * d * F32                      # own rows
              + rows * k * d * F32                # gathered neighbour rows
@@ -79,7 +70,7 @@ def memory_update_table_cost(model: dict, occurrences: int,
     `occurrences` endpoint occurrences, `written` of which write their row
     back (one per distinct node)."""
     d, dm = model["d_mem"], model["d_msg"]
-    flops = _matmul(occurrences, dm, 3 * d) + _matmul(occurrences, d, 3 * d)
+    flops = matmul(occurrences, dm, 3 * d) + matmul(occurrences, d, 3 * d)
     moved = (occurrences * d * F32              # gathered rows
              + written * d * F32                # written rows
              + occurrences * dm * F32           # messages

@@ -22,6 +22,7 @@ def context(spec: dict, out: dict, devs, chip=None) -> types.SimpleNamespace:
         written_per_step=traced.get("written_per_step"),
         calls=traced.get("calls"),
         model=spec["config"]["model"], traffic=spec["traffic"],
+        arch=cell.config_module(spec["config"]["name"]),
         chips=len(devs),
         peaks=chip or peaks.chip_peaks(devs[0].device_kind),
         tr=tracereduce, flops=flops)

@@ -1,4 +1,4 @@
-"""Plain reference of TGN-PRES and JODIE-PRES training, in `jax.numpy`.
+"""Plain reference of MDGNN-PRES training and serving, in `jax.numpy`.
 
 Written from the papers and the configuration, not from the program: it
 imports nothing of `src/` and takes nothing the program made. The
@@ -6,33 +6,39 @@ benchmark makes the weights (`init_params`) and the events, hands them to
 the program and to this reference alike, and compares what comes out
 (`bench/lib/compare.py`).
 
+This module holds what every MDGNN shares. What differs from one to
+another (the embedding's weights and forward pass, tables the model adds
+to the node state and their upkeep, and which of those must match
+exactly) lives in the configuration's own module,
+`bench/configs/<config>.py` (`bench/lib/cell.py::config_module`), which
+every function here that needs it takes first, as `arch`.
+
 One lag-one training step (TGN, Rossi et al. 2020; PRES, Su et al. 2024,
 Alg. 2): the previous temporal batch updates the memory, then the current
 batch and its negatives are scored from embeddings of the updated memory.
 
 * MESSAGE: for each endpoint occurrence (sources, then destinations)
   m = MLP([s_self, s_other, e, cos(dt w + b)]), dt = t - last_update.
-* MEMORY: s_meas = GRU(m, s_self) (TGN) or tanh(m W + s U + b) (JODIE).
+* MEMORY: s_meas = GRU(m, s_self), or tanh(m W + s U + b) for an RNN cell.
 * PRES: s_pred = s_self + clip(c * mean_delta, +-clip), where c counts the
   node's occurrences in the batch and mean_delta is the GMM trackers'
   mixture mean; fused = (1 - g) s_pred + g s_meas with g = sigmoid(gamma);
   the delta rate (fused - s_self) / max(c, 1) feeds the trackers.
   Each node's chronologically last occurrence (ties: the later one in
   source-then-destination order) writes its fused row and time.
-* EMBEDDING: TGN, one layer of two-head attention over the K most recent
-  neighbours, keys and values from [s_nbr, cos(dt w + b)], then
-  relu([agg, s_self] Wo). JODIE: tanh((s * (1 + dt w_proj)) W_out).
+* EMBEDDING: the configuration's module (`arch.embed`), from the updated
+  memory, the last-update times and the neighbour rings.
 * DECODER: relu([h_src, h_dst] W1 + b1) W2 + b2; loss = masked mean BCE
   over positives and negatives + beta * (1 - cos(s_prev, fused)) over the
   written rows (PRES Eq. 10).
-* Adam on every weight, then the trackers take the batch's delta rates and
-  the neighbour rings take the previous batch (the last K per node).
+* Adam on every weight, then the trackers take the batch's delta rates,
+  the neighbour rings take the previous batch (the last K per node), and
+  the module's own tables are kept up (`arch.maintain_extra`).
 
 Departures of the repository's architecture from the publications, which
 this reference shares because it checks the program: the message is a
-two-layer MLP (TGN's default is the identity message), attention keys do
-not include edge features, there is no dropout, and JODIE's projection is
-followed by a 100 x 100 layer and tanh.
+two-layer MLP (TGN's default is the identity message) and there is no
+dropout. A module names its own departures.
 
 `dtype` is the precision every model value is computed in: float32 for the
 reference (under `highest` matmul precision), bfloat16 for the control.
@@ -58,12 +64,12 @@ N_COMPONENTS = 2   # PRES GMM components (positive / negative event types)
 # ---------------------------------------------------------------------------
 
 
-def param_shapes(m: dict, d_edge: int) -> dict:
+def param_shapes(arch, m: dict, d_edge: int) -> dict:
     """Shapes of every weight, by the names the program's tree uses."""
     d_mem, d_msg, d_time, d_emb = m["d_mem"], m["d_msg"], m["d_time"], \
         m["d_embed"]
     gates = 3 if m["memory_cell"] == "gru" else 1
-    shapes = {
+    return {
         "time": {"w": (d_time,), "b": (d_time,)},
         "msg": {"w1": (2 * d_mem + d_edge + d_time, d_msg), "b1": (d_msg,),
                 "w2": (d_msg, d_msg), "b2": (d_msg,)},
@@ -74,24 +80,15 @@ def param_shapes(m: dict, d_edge: int) -> dict:
         "node_cls": {"w1": (d_emb, d_emb), "b1": (d_emb,), "w2": (d_emb, 1),
                      "b2": (1,)},
         "pres": {"gamma_logit": ()},
+        "emb": arch.emb_shapes(m, d_edge),
     }
-    if m["variant"] == "tgn":
-        shapes["emb"] = {"l0": {"wq": (d_mem, d_emb),
-                                "wk": (d_mem + d_time, d_emb),
-                                "wv": (d_mem + d_time, d_emb),
-                                "wo": (d_emb + d_mem, d_emb)}}
-    elif m["variant"] == "jodie":
-        shapes["emb"] = {"l0": {"w_proj": (1, d_mem), "w_out": (d_mem, d_emb)}}
-    else:
-        raise ValueError(f"no reference for variant {m['variant']!r}")
-    return shapes
 
 
-def init_params(key, m: dict, d_edge: int):
+def init_params(arch, key, m: dict, d_edge: int):
     """Seeded weights, made on the device in one call: matrices
     N(0, 1/fan_in), biases and gamma zero, the time encoder at TGN's fixed
     frequencies 10 ** -linspace(0, 9, d_time)."""
-    shapes = param_shapes(m, d_edge)
+    shapes = param_shapes(arch, m, d_edge)
     flat, tree = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     keys = jax.random.split(key, len(flat))
@@ -117,7 +114,8 @@ def init_params(key, m: dict, d_edge: int):
 # ---------------------------------------------------------------------------
 
 
-def init_state(n_nodes: int, m: dict, dtype=jnp.float32) -> dict:
+def init_state(arch, n_nodes: int, m: dict, dtype=jnp.float32) -> dict:
+    """The node state every MDGNN keeps, and the module's own tables."""
     d, k = m["d_mem"], m["n_neighbors"]
     return {
         "mem": jnp.zeros((n_nodes, d), dtype),
@@ -128,6 +126,7 @@ def init_state(n_nodes: int, m: dict, dtype=jnp.float32) -> dict:
         "pres_n": jnp.zeros((n_nodes, N_COMPONENTS), dtype),
         "pres_xi": jnp.zeros((n_nodes, N_COMPONENTS, d), dtype),
         "pres_psi": jnp.zeros((n_nodes, N_COMPONENTS, d), dtype),
+        **arch.extra_state(n_nodes, m, dtype),
     }
 
 
@@ -171,7 +170,8 @@ def step_keys(key, n_steps: int):
 # ---------------------------------------------------------------------------
 
 
-def _time_enc(p, dt, dtype):
+def time_enc(p, dt, dtype):
+    """TGN's time encoding cos(dt w + b), computed in float32."""
     return jnp.cos(dt[..., None] * p["w"].astype(jnp.float32)
                    + p["b"].astype(jnp.float32)).astype(dtype)
 
@@ -212,8 +212,8 @@ def memory_stage(m, cfg, params, state, prev, dtype):
     mask = jnp.concatenate([prev["mask"], prev["mask"]])
     s_self = state["mem"][nodes]
     s_other = state["mem"][other]
-    t_enc = _time_enc(params["time"], times - state["last_update"][nodes],
-                      dtype)
+    t_enc = time_enc(params["time"], times - state["last_update"][nodes],
+                     dtype)
     pm = params["msg"]
     x = jnp.concatenate([s_self, s_other, feat, t_enc], axis=-1)
     msg = jax.nn.relu(x @ pm["w1"] + pm["b1"]) @ pm["w2"] + pm["b2"]
@@ -242,41 +242,14 @@ def memory_stage(m, cfg, params, state, prev, dtype):
                        "fused": fused, "delta": delta}
 
 
-def embed(m, params, mem, last, state, rows, t_query, dtype):
-    if m["variant"] == "jodie":
-        lp = params["emb"]["l0"]
-        dt = (t_query - last[rows]).astype(dtype)
-        proj = mem[rows] * (1 + dt[:, None] * lp["w_proj"][0])
-        return jnp.tanh(proj @ lp["w_out"])
-    lp = params["emb"]["l0"]
-    nbr, nbr_t = state["nbr"][rows], state["nbr_t"][rows]
-    valid = nbr >= 0
-    h_self = mem[rows]
-    h_nbr = mem[jnp.maximum(nbr, 0)]
-    t_enc = _time_enc(params["time"], t_query[:, None] - nbr_t, dtype)
-    kv = jnp.concatenate([h_nbr, t_enc], axis=-1)
-    q, k, v = h_self @ lp["wq"], kv @ lp["wk"], kv @ lp["wv"]
-    heads = m["n_heads"]
-    r, kk, e = k.shape
-    dh = e // heads
-    q = q.reshape(r, heads, dh)
-    k = k.reshape(r, kk, heads, dh)
-    v = v.reshape(r, kk, heads, dh)
-    score = jnp.einsum("rhd,rkhd->rhk", q, k) / math.sqrt(dh)
-    score = jnp.where(valid[:, None, :], score, -1e30)
-    prob = jax.nn.softmax(score.astype(jnp.float32), axis=-1).astype(dtype)
-    prob = jnp.where(jnp.any(valid, -1)[:, None, None], prob, 0)
-    agg = jnp.einsum("rhk,rkhd->rhd", prob, v).reshape(r, e)
-    return jax.nn.relu(jnp.concatenate([agg, h_self], axis=-1) @ lp["wo"])
-
-
 def _decode(params, hs, hd):
     p = params["dec"]
     h = jax.nn.relu(jnp.concatenate([hs, hd], axis=-1) @ p["w1"] + p["b1"])
     return (h @ p["w2"] + p["b2"])[:, 0]
 
 
-def loss_fn(params, m, cfg, state, prev, pos, neg, dtype, half_batch=False):
+def loss_fn(params, arch, m, cfg, state, prev, pos, neg, dtype,
+            half_batch=False):
     """The training loss of one step, and what the step carries on.
     `half_batch` plants a fault for the benchmark's own tests: the BCE is
     the mean over the first half of the batch only."""
@@ -284,7 +257,7 @@ def loss_fn(params, m, cfg, state, prev, pos, neg, dtype, half_batch=False):
     b = pos["src"].shape[0]
     rows = jnp.concatenate([pos["src"], pos["dst"], neg["src"], neg["dst"]])
     tq = jnp.concatenate([pos["t"], pos["t"], neg["t"], neg["t"]])
-    h = embed(m, params, mem, last, state, rows, tq, dtype)
+    h = arch.embed(m, params, mem, last, state, rows, tq, dtype)
     lp = _decode(params, h[:b], h[b:2 * b])
     ln = _decode(params, h[2 * b:3 * b], h[3 * b:])
     pmask, nmask = pos["mask"].astype(dtype), neg["mask"].astype(dtype)
@@ -341,10 +314,11 @@ def adam(opt_cfg, params, grads, opt, dtype):
     return new, {"mu": mu, "nu": nu, "step": step}
 
 
-def maintain(m, state, mem, last, occ, dtype):
-    """The state after a batch: its memory and times, the trackers with
-    the batch's delta rates (Eq. 9, component 0) and the neighbour rings
-    with its events."""
+def maintain(arch, m, params, state, prev, mem, last, occ, dtype):
+    """The state after a batch `prev`: its memory and times, the trackers
+    with the batch's delta rates (Eq. 9, component 0), the neighbour rings
+    with its events, and the module's own tables (`params`: the weights
+    after the step)."""
     n_rows = state["pres_n"].size
     flat = jnp.where(occ["sel"] & occ["mask"], occ["nodes"] * N_COMPONENTS,
                      n_rows)
@@ -355,48 +329,53 @@ def maintain(m, state, mem, last, occ, dtype):
         return t2.at[flat].add(rows, mode="drop").reshape(table.shape)
 
     nbr, nbr_t, ptr = _ring_append(state, occ, m["n_neighbors"])
-    return {
+    new = {
         "mem": jax.lax.stop_gradient(mem), "last_update": last,
         "nbr": nbr, "nbr_t": nbr_t, "ptr": ptr,
         "pres_n": add(state["pres_n"], jnp.ones(flat.shape, dtype)),
         "pres_xi": add(state["pres_xi"], delta),
         "pres_psi": add(state["pres_psi"], delta * delta),
     }
+    return {**new, **arch.maintain_extra(m, params, state, new, prev, occ,
+                                         dtype)}
 
 
-def train_step(m, cfg, opt_cfg, dtype, half_batch, params, opt, state, prev,
-               pos, neg):
+def train_step(arch, m, cfg, opt_cfg, dtype, half_batch, params, opt, state,
+               prev, pos, neg):
     """One reference training step. Returns (params, opt, state, loss,
     grads)."""
     (loss, (mem, last, occ)), grads = jax.value_and_grad(
-        loss_fn, has_aux=True)(params, m, cfg, state, prev, pos, neg, dtype,
-                               half_batch)
+        loss_fn, has_aux=True)(params, arch, m, cfg, state, prev, pos, neg,
+                               dtype, half_batch)
     params, opt = adam(opt_cfg, params, grads, opt, dtype)
-    return params, opt, maintain(m, state, mem, last, occ, dtype), loss, grads
+    return (params, opt,
+            maintain(arch, m, params, state, prev, mem, last, occ, dtype),
+            loss, grads)
 
 
-def fold(m, cfg, dtype, params, state, events):
+def fold(arch, m, cfg, dtype, params, state, events):
     """Serving: fold a batch of events into the state (no training)."""
     mem, last, occ = memory_stage(m, cfg, params, state, events, dtype)
-    return maintain(m, state, mem, last, occ, dtype)
+    return maintain(arch, m, params, state, events, mem, last, occ, dtype)
 
 
-def link_scores(m, dtype, params, state, src, dst, t):
+def link_scores(arch, m, dtype, params, state, src, dst, t):
     """Serving: link logits of (src, dst) pairs at times t."""
     b = src.shape[0]
-    h = embed(m, params, state["mem"], state["last_update"], state,
-              jnp.concatenate([src, dst]), jnp.concatenate([t, t]), dtype)
+    h = arch.embed(m, params, state["mem"], state["last_update"], state,
+                   jnp.concatenate([src, dst]), jnp.concatenate([t, t]),
+                   dtype)
     return _decode(params, h[:b], h[b:])
 
 
-def item_scores(m, dtype, params, state, src, t, items):
+def item_scores(arch, m, dtype, params, state, src, t, items):
     """Serving: logits of every source against every item, the items
     embedded once at the latest query time of the request."""
     b, n = src.shape[0], items.shape[0]
     t_item = jnp.full((n,), jnp.max(t), jnp.float32)
-    h = embed(m, params, state["mem"], state["last_update"], state,
-              jnp.concatenate([src, items]), jnp.concatenate([t, t_item]),
-              dtype)
+    h = arch.embed(m, params, state["mem"], state["last_update"], state,
+                   jnp.concatenate([src, items]),
+                   jnp.concatenate([t, t_item]), dtype)
     hs, hi = h[:b], h[b:]
     pair = jnp.concatenate([jnp.repeat(hs, n, axis=0),
                             jnp.tile(hi, (b, 1))], axis=-1)
@@ -406,14 +385,16 @@ def item_scores(m, dtype, params, state, src, t, items):
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_step(m_items, cfg_items, opt_items, dtype_name, half_batch):
+def _jitted_step(arch, m_items, cfg_items, opt_items, dtype_name,
+                 half_batch):
     m, cfg, opt_cfg = dict(m_items), dict(cfg_items), dict(opt_items)
     dtype = jnp.dtype(dtype_name)
-    fn = functools.partial(train_step, m, cfg, opt_cfg, dtype, half_batch)
+    fn = functools.partial(train_step, arch, m, cfg, opt_cfg, dtype,
+                           half_batch)
     return jax.jit(fn)
 
 
-def run(model: dict, params, stream, batch_size: int, dst_range, key,
+def run(arch, model: dict, params, stream, batch_size: int, dst_range, key,
         n_steps: int, dtype=jnp.float32, half_batch=False, precision=None):
     """`n_steps` reference steps from the seeded weights and an empty
     state, over batches 0..n_steps of `stream` = (src, dst, t, feat), with
@@ -422,7 +403,8 @@ def run(model: dict, params, stream, batch_size: int, dst_range, key,
     m = model["model"]
     cfg = {"pres_clip": m["pres_clip"], "beta": m["beta"]}
     opt_cfg = {k: model["optimizer"][k] for k in ("lr", "b1", "b2", "eps")}
-    fn = _jitted_step(tuple(sorted(m.items())), tuple(sorted(cfg.items())),
+    fn = _jitted_step(arch, tuple(sorted(m.items())),
+                      tuple(sorted(cfg.items())),
                       tuple(sorted(opt_cfg.items())), jnp.dtype(dtype).name,
                       half_batch)
     n_nodes = model["n_nodes"]
@@ -430,7 +412,7 @@ def run(model: dict, params, stream, batch_size: int, dst_range, key,
     opt = {"mu": jax.tree.map(jnp.zeros_like, params),
            "nu": jax.tree.map(jnp.zeros_like, params),
            "step": jnp.zeros((), jnp.int32)}
-    state = init_state(n_nodes, m, dtype)
+    state = init_state(arch, n_nodes, m, dtype)
     keys = step_keys(key, n_steps)
     out = []
     prec = precision or ("highest" if jnp.dtype(dtype) == jnp.float32

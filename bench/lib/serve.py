@@ -26,8 +26,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.lib import compare, device, reference, streams
-from bench.lib.train import program_config, program_state, seed_key
+from bench.lib import cell, compare, device, reference, streams
+from bench.lib.train import (extra_tables, program_config, program_state,
+                             seed_key)
 
 # the engine's request buckets (the program's MicroBatcher default), which
 # the reference pads to as well, so that neither compiles in the window
@@ -76,6 +77,7 @@ def run_cell(config: dict, traffic: dict, limits: dict, seed: int,
     from repro.serve import MicroBatcher, ServeEngine
 
     counter = device.compile_counter()
+    arch = cell.config_module(config["name"])
     g = traffic["graph"]
     rate = rate or traffic["rate_events_per_s"]
     if trace:
@@ -92,7 +94,7 @@ def run_cell(config: dict, traffic: dict, limits: dict, seed: int,
         (src, dst, t, feat), neg, due = make_stream(traffic, seed, seconds,
                                                     rate)
         phases["stream"] = time.perf_counter() - t_start
-        params = reference.init_params(jax.random.fold_in(key, 0),
+        params = reference.init_params(arch, jax.random.fold_in(key, 0),
                                        config["model"], g["feat_dim"])
         params0 = jax.device_get(params)
         engine = ServeEngine(cfg, params, mdgnn.init_state(cfg),
@@ -165,7 +167,8 @@ def run_cell(config: dict, traffic: dict, limits: dict, seed: int,
         "setup_s": {"value": setup_s, "unit": "s"}}
     peak = device.memory_peak_bytes(devs)
     dispatch = kops.dispatch_log()
-    prog_state = jax.device_get(program_state(engine.state))
+    prog_state = jax.device_get(program_state(
+        engine.state, extra_tables(arch, config["model"])))
     calls = {"ingest": len(rounds), "query": len(rounds),
              "topk": len(topk_out)}
     del engine, params
@@ -182,7 +185,7 @@ def run_cell(config: dict, traffic: dict, limits: dict, seed: int,
         {"scores": np.concatenate(q_out),
          "topk_vals": [v for _, v, _ in topk_out],
          "topk_ids": [i for _, _, i in topk_out], "state": prog_state},
-        ref, k)
+        ref, k, arch.EXACT)
     correct, checks = compare.judge(numbers, limits["limits"])
     not_compiled = {kk: v for kk, v in dispatch.items()
                     if set(v) != {"compiled"}}
@@ -217,19 +220,21 @@ def replay(config, traffic, params0, stream, neg, n_pre, rounds, topk_asks,
     """The reference over the same rounds: the prefix folded in blocks of
     the largest bucket, then per round the link scores, the top-k answers
     and the fold, each padded to a bucket with masked rows."""
+    arch = cell.config_module(config["name"])
     m = config["model"]
     cfg = {"pres_clip": m["pres_clip"], "beta": m["beta"]}
     src, dst, t, feat = stream
     g = traffic["graph"]
     n_nodes = g["n_users"] + g["n_items"]
     params = jax.tree.map(lambda p: jnp.asarray(p).astype(dtype), params0)
-    state = reference.init_state(n_nodes, m, dtype)
-    fold = jax.jit(lambda p, s, e: reference.fold(m, cfg, dtype, p, s, e))
+    state = reference.init_state(arch, n_nodes, m, dtype)
+    fold = jax.jit(lambda p, s, e: reference.fold(arch, m, cfg, dtype, p, s,
+                                                  e))
     score = jax.jit(lambda p, s, a, b_, c: reference.link_scores(
-        m, dtype, p, s, a, b_, c))
+        arch, m, dtype, p, s, a, b_, c))
     item_ids = jnp.arange(items[0], items[1], dtype=jnp.int32)
     every = jax.jit(lambda p, s, a, c: reference.item_scores(
-        m, dtype, p, s, a, c, item_ids))
+        arch, m, dtype, p, s, a, c, item_ids))
 
     def pad(a, n):
         return np.concatenate([a, np.zeros((n - len(a),) + a.shape[1:],
@@ -281,7 +286,7 @@ def replay(config, traffic, params0, stream, neg, n_pre, rounds, topk_asks,
             "state": jax.device_get(state)}
 
 
-def serve_numbers(prog: dict, ref: dict, k: int) -> dict:
+def serve_numbers(prog: dict, ref: dict, k: int, exact=()) -> dict:
     """The numbers compared for a serving cell:
 
     * `score_gap`: the widest |link score - reference score|;
@@ -292,7 +297,8 @@ def serve_numbers(prog: dict, ref: dict, k: int) -> dict:
     * `topk_score_gap`: the widest |top-k score - reference score of the
       same item|;
     * `state_gap` and `state_mismatch`: the state after the window, as
-      for training (`compare.state_numbers`).
+      for training (`compare.state_numbers`, with the module's `exact`
+      tables).
     """
     scores = np.asarray(prog["scores"], np.float64)
     sg = float(np.max(np.abs(scores - ref["scores"]))) if len(scores) else 0.0
@@ -305,6 +311,6 @@ def serve_numbers(prog: dict, ref: dict, k: int) -> dict:
         rank_gap = max(rank_gap, float(np.max(kth - got)))
         score_gap = max(score_gap, float(np.max(np.abs(
             np.asarray(vals, np.float64) - got))))
-    state, _ = compare.state_numbers(prog["state"], ref["state"])
+    state, _ = compare.state_numbers(prog["state"], ref["state"], exact)
     return {"score_gap": sg, "topk_rank_gap": rank_gap,
             "topk_score_gap": score_gap, **state}

@@ -18,15 +18,17 @@ The program path is set here and nowhere else (`PROGRAM_PATH`). One run:
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import re
 import shutil
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from bench.lib import compare, device, reference, streams
+from bench.lib import cell, compare, device, reference, streams
 
 # the program path every training cell measures
 PROGRAM_PATH = dict(use_kernels=True, kernels_mode="auto", pipeline_depth=0,
@@ -55,34 +57,59 @@ def model_spec(config: dict, traffic: dict) -> dict:
 
 
 def program_config(config: dict, traffic: dict):
+    """The program's `MDGNNConfig`: every key of the configuration's
+    `model`, the graph's sizes and `PROGRAM_PATH`. A model key the program
+    has no field for is refused, unless the configuration's module lists
+    it in `NOT_TAKEN` at the value the configuration gives, so that no
+    configuration sets a width the program would silently ignore."""
     from repro.models.mdgnn import MDGNNConfig
     m, g = config["model"], traffic["graph"]
-    return MDGNNConfig(
-        variant=m["variant"], n_nodes=g["n_users"] + g["n_items"],
-        d_edge=g["feat_dim"], d_mem=m["d_mem"], d_msg=m["d_msg"],
-        d_time=m["d_time"], d_embed=m["d_embed"],
-        n_neighbors=m["n_neighbors"], n_layers=m["n_layers"],
-        n_heads=m["n_heads"], memory_cell=m["memory_cell"],
-        use_pres=m["use_pres"], beta=m["beta"], delta_mode=m["delta_mode"],
-        pres_scale=m["pres_scale"], pres_clip=m["pres_clip"],
-        **PROGRAM_PATH)
+    not_taken = cell.config_module(config["name"]).NOT_TAKEN
+    fields = {f.name for f in dataclasses.fields(MDGNNConfig)}
+    harness = set(PROGRAM_PATH) | {"n_nodes", "d_edge"}
+    for k, v in m.items():
+        if k in harness:
+            raise ValueError(f"model key {k!r} is set by the harness, not "
+                             "by a configuration")
+        if k not in fields and (k not in not_taken or not_taken[k] != v):
+            raise ValueError(
+                f"the program has no field for model key {k!r} = {v!r}; "
+                f"NOT_TAKEN allows {not_taken}")
+    return MDGNNConfig(n_nodes=g["n_users"] + g["n_items"],
+                       d_edge=g["feat_dim"],
+                       **{k: v for k, v in m.items() if k in fields},
+                       **PROGRAM_PATH)
 
 
-def program_state(state) -> dict:
-    """The program's node state under the reference's table names."""
-    return {"mem": state["memory"].mem,
-            "last_update": state["memory"].last_update,
-            "nbr": state["neighbors"]["nbr"], "nbr_t": state["neighbors"]["t"],
-            "ptr": state["neighbors"]["ptr"], "pres_n": state["pres"].n,
-            "pres_xi": state["pres"].xi, "pres_psi": state["pres"].psi}
+def extra_tables(arch, m: dict) -> tuple:
+    """The names of the tables the configuration's module adds to the node
+    state: paths into the program's state tree, as `group/key`."""
+    return tuple(jax.eval_shape(lambda: arch.extra_state(1, m, jnp.float32)))
+
+
+def program_state(state, extra=()) -> dict:
+    """The program's node state under the reference's table names: the
+    shared tables, and each of the module's `extra` tables by its path."""
+    out = {"mem": state["memory"].mem,
+           "last_update": state["memory"].last_update,
+           "nbr": state["neighbors"]["nbr"], "nbr_t": state["neighbors"]["t"],
+           "ptr": state["neighbors"]["ptr"], "pres_n": state["pres"].n,
+           "pres_xi": state["pres"].xi, "pres_psi": state["pres"].psi}
+    for name in extra:
+        table = state
+        for part in name.split("/"):
+            table = table[part]
+        out[name] = table
+    return out
 
 
 class Recorder:
     """The step the harness hands to `run_epoch`: the program's step in a
     `TraceAnnotation`, keeping what the check needs from the first calls."""
 
-    def __init__(self, step, n_check: int, b1: float):
+    def __init__(self, step, n_check: int, b1: float, extra=()):
         self.step, self.n_check, self.b1 = step, n_check, b1
+        self.extra = extra
         self.calls = 0
         self.shapes = None
         self.losses, self.grads, self.params_end, self.state_end = \
@@ -105,7 +132,8 @@ class Recorder:
                                           jax.device_get(opt2["mu"]))
             if i == self.n_check - 1:
                 self.params_end = jax.device_get(params2)
-                self.state_end = jax.device_get(program_state(state2))
+                self.state_end = jax.device_get(
+                    program_state(state2, self.extra))
         return out
 
     def scopes(self) -> dict:
@@ -131,6 +159,7 @@ def run_cell(config: dict, traffic: dict, limits: dict, seed: int,
     from repro.models import mdgnn
 
     counter = device.compile_counter()
+    arch = cell.config_module(config["name"])
     g = traffic["graph"]
     bsz = traffic["batch_size"]
     n_check = traffic["check_steps"]
@@ -144,7 +173,7 @@ def run_cell(config: dict, traffic: dict, limits: dict, seed: int,
     with jax.profiler.TraceAnnotation("bench.setup"):
         stream = make_stream(traffic, seed)
         phases["stream"] = time.perf_counter() - t_start
-        params = reference.init_params(jax.random.fold_in(key, 0),
+        params = reference.init_params(arch, jax.random.fold_in(key, 0),
                                        config["model"], g["feat_dim"])
         want = jax.eval_shape(lambda k: mdgnn.init_params(k, cfg)[0],
                               jax.random.PRNGKey(0))
@@ -167,7 +196,8 @@ def run_cell(config: dict, traffic: dict, limits: dict, seed: int,
                                    for i in range(1, len(batches))))
         steps_per_epoch = len(batches) - 1
         kops.reset_dispatch_log()
-        rec = Recorder(step, n_check, opt_cfg["b1"])
+        rec = Recorder(step, n_check, opt_cfg["b1"],
+                       extra_tables(arch, config["model"]))
         params, opt_state, state, _ = pipeline.run_epoch(
             params, opt_state, state, batches[:n_check + 1], cfg, rec,
             jax.random.fold_in(key, 1), dst_range)
@@ -235,12 +265,12 @@ def run_cell(config: dict, traffic: dict, limits: dict, seed: int,
     del params, opt_state, state, batches, rec, step
     gc.collect()
     ref_steps, ref_params, ref_state = reference.run(
-        model_spec(config, traffic), params0, stream, bsz, dst_range,
+        arch, model_spec(config, traffic), params0, stream, bsz, dst_range,
         jax.random.fold_in(key, 1), n_check)
     ref = {"losses": [r["loss"] for r in ref_steps],
            "grads": ref_steps[0]["grads"], "params0": params0,
            "params_end": ref_params, "state_end": ref_state}
-    numbers, where = compare.training_numbers(prog, ref)
+    numbers, where = compare.training_numbers(prog, ref, arch.EXACT)
     correct, checks = compare.judge(numbers, limits["limits"])
     not_compiled = {k: v for k, v in dispatch.items()
                     if set(v) != {"compiled"}}

@@ -1,9 +1,9 @@
 """Operation and byte counts, checked by hand at small shapes."""
-from bench.lib import flops
+from bench.lib import cell, flops
 
 TGN = {"variant": "tgn", "memory_cell": "gru", "d_mem": 4, "d_msg": 3,
        "d_time": 2, "d_embed": 4, "n_neighbors": 5}
-JODIE = dict(TGN, variant="jodie", memory_cell="rnn")
+RNN = dict(TGN, memory_cell="rnn")
 
 
 def test_memory_stage_flops_by_hand():
@@ -12,22 +12,22 @@ def test_memory_stage_flops_by_hand():
     gru = 2 * 6 * 3 * 12 + 2 * 6 * 4 * 12      # x W and h U, 3 gates
     rnn = 2 * 6 * 3 * 4 + 2 * 6 * 4 * 4
     assert flops.memory_stage_flops(TGN, 7, 6) == msg + gru
-    assert flops.memory_stage_flops(JODIE, 7, 6) == msg + rnn
+    assert flops.memory_stage_flops(RNN, 7, 6) == msg + rnn
 
 
 def test_embed_and_step_flops_by_hand():
+    tgn = cell.config_module("tgn-pres")
     rows = 8
     q = 2 * 8 * 4 * 4
     kv = 2 * (2 * 40 * 6 * 4)
     attn = 2 * 2 * 40 * 4
     out = 2 * 8 * 8 * 4
-    assert flops.embed_flops(TGN, rows) == q + kv + attn + out
-    assert flops.embed_flops(JODIE, rows) == 2 * 8 * 4 * 4
+    assert tgn.embed_flops(TGN, rows) == q + kv + attn + out
     dec = 2 * 4 * 8 * 4 + 2 * 4 * 4 * 1
     assert flops.decoder_flops(TGN, 4) == dec
     step = 3 * (flops.memory_stage_flops(TGN, 7, 4)
-                + flops.embed_flops(TGN, 8) + flops.decoder_flops(TGN, 4))
-    assert flops.train_step_flops(TGN, 7, 2) == step
+                + tgn.embed_flops(TGN, 8) + flops.decoder_flops(TGN, 4))
+    assert flops.train_step_flops(TGN, 7, 2, tgn) == step
 
 
 def test_embed_attn_bytes_count_gathered_rows_not_the_table():

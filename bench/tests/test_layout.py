@@ -14,6 +14,57 @@ from bench.lib import cell, flops, tracereduce
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+CONFIGS = [c["name"] for c in SPEC["configs"]]
+
+
+def _first_cell(config: str) -> dict:
+    """The first cell of a configuration, for the widths it runs at."""
+    name = next(w["name"] for w in SPEC["workloads"] if w["config"] == config)
+    return cell.workload(name, SPEC)
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_config_module_loads(config):
+    mod = cell.config_module(config)
+    assert all(hasattr(mod, n) for n in cell.MODULE_NAMES)
+    assert cell.config_module(config) is mod     # loaded once
+    assert _first_cell(config)["module"] is mod
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_emb_shapes_are_the_programs(config):
+    import jax
+    from bench.lib import train
+    from repro.models import mdgnn
+    w = _first_cell(config)
+    cfg = train.program_config(w["config"], w["traffic"])
+    want = jax.eval_shape(lambda k: mdgnn.init_params(k, cfg)[0]["emb"],
+                          jax.random.PRNGKey(0))
+    got = w["module"].emb_shapes(w["config"]["model"],
+                                 w["traffic"]["graph"]["feat_dim"])
+    assert jax.tree.map(lambda a: tuple(a.shape), want) == got
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_every_model_key_reaches_the_program(config):
+    """A model key is a field of the program's config, or the module names
+    it in NOT_TAKEN at the value the configuration gives."""
+    import dataclasses
+    from repro.models.mdgnn import MDGNNConfig
+    fields = {f.name for f in dataclasses.fields(MDGNNConfig)}
+    w = _first_cell(config)
+    not_taken = w["module"].NOT_TAKEN
+    for k, v in w["config"]["model"].items():
+        assert k in fields or (k in not_taken and not_taken[k] == v), k
+
+
+def test_train_step_flops_of_the_wikipedia_cell():
+    """The FLOP count behind `mfu.train` in tgn-pres.train.wikipedia, as the
+    shared count gave it before the embedding's moved to the module."""
+    w = cell.workload("tgn-pres.train.wikipedia", SPEC)
+    assert flops.train_step_flops(
+        w["config"]["model"], w["traffic"]["graph"]["feat_dim"],
+        w["traffic"]["batch_size"], w["module"]) == 12015600000.0
 
 
 @pytest.mark.parametrize("name", [w["name"] for w in SPEC["workloads"]])
@@ -42,7 +93,7 @@ def test_every_metric_reader_reads_nothing_from_an_empty_trace(name):
         traffic=json.loads((ROOT / "bench/workloads/train.wikipedia.json"
                             ).read_text()),
         peaks={"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9},
-        tr=tracereduce, flops=flops)
+        arch=cell.config_module("tgn-pres"), tr=tracereduce, flops=flops)
     value = read(ctx)
     # nothing traced: a share of a roofline or a stage time is absent, not 0
     assert value is None

@@ -118,7 +118,8 @@ def test_topk_request_over_the_largest_bucket_replays_in_blocks():
     items = (g["n_users"], g["n_users"] + g["n_items"])
     n_pre, n_ask = traffic["prefix_events"], serve.BUCKETS[-1] + 76
     (src, dst, t, feat), neg, _ = serve.make_stream(traffic, SEED, 3.0, 400)
-    params = serve.reference.init_params(jax.random.PRNGKey(SEED),
+    params = serve.reference.init_params(cell.config_module("tgn-pres"),
+                                         jax.random.PRNGKey(SEED),
                                          CONFIG["model"], g["feat_dim"])
     cfg = serve.program_config(CONFIG, traffic)
     engine = ServeEngine(cfg, params, mdgnn.init_state(cfg),
