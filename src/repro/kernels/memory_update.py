@@ -12,7 +12,11 @@ won in exactly this scatter/update primitive.
 
 `memory_update_table` is the table-level form the training step actually
 dispatches: the same fused math with the memory-row gather and the
-write-back scatter pulled INTO the kernel. The (N, D) table stays in HBM
+write-back scatter pulled INTO the kernel. Its memory cell is a static
+choice (`cell`): the GRU (TGN, APAN) or the RNN tanh(x W + h U + b)
+(JODIE), whose one gate replaces the GRU's three inside the same tile
+pass; the RNN form's `pallas_call` is named `memory_update_table_rnn`.
+The (N, D) table stays in HBM
 (`memory_space=pl.ANY`, aliased in place); each grid step DMAs its
 `block_m` gathered rows into a VMEM tile and DMAs the fused rows back
 (docs/KERNELS.md §memory_update_table — including the occurrence-order
@@ -41,20 +45,46 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import tiling
 
 
-def _gru_pres(x, h, w, u, b, dmean, scale, gamma, *, clip, delta_mode):
-    """The fused GRU + PRES math on one VMEM tile (values, not refs):
-    x (bm, Din), h/dmean (bm, D), scale (bm, 1), b (1, 3D), gamma scalar.
-    Returns (s_meas, fused, delta)."""
-    # ---- GRU gates: both matmuls back-to-back on the MXU ------------------
-    gx = jnp.dot(x, w, preferred_element_type=jnp.float32) + b
-    gh = jnp.dot(h, u, preferred_element_type=jnp.float32)
-    d = h.shape[-1]
-    rx, zx, nx = gx[:, :d], gx[:, d:2 * d], gx[:, 2 * d:]
-    rh, zh, nh = gh[:, :d], gh[:, d:2 * d], gh[:, 2 * d:]
-    r = jax.nn.sigmoid(rx + rh)
-    z = jax.nn.sigmoid(zx + zh)
-    n = jnp.tanh(nx + r * nh)
-    s_meas = (1.0 - z) * h + z * n
+# the memory cells the table kernel computes, and their gates
+GATES = {"gru": 3, "rnn": 1}
+
+
+def _check_cell(cell: str) -> None:
+    if cell not in GATES:
+        raise ValueError(f"unknown memory cell {cell!r}; the fused kernels "
+                         f"compute {', '.join(GATES)}")
+
+
+def table_kernel_name(cell: str) -> str:
+    """The `pallas_call` name of the table kernel's form for `cell`: the
+    GRU form keeps the historical name, any other cell is suffixed, so a
+    profiler trace tells the forms apart."""
+    _check_cell(cell)
+    return "memory_update_table" if cell == "gru" else \
+        f"memory_update_table_{cell}"
+
+
+def _cell_pres(x, h, w, u, b, dmean, scale, gamma, *, cell, clip,
+               delta_mode):
+    """The fused memory cell + PRES math on one VMEM tile (values, not
+    refs): x (bm, Din), h/dmean (bm, D), scale (bm, 1), b (1, G*D) with
+    G = GATES[cell], gamma scalar. Returns (s_meas, fused, delta)."""
+    if cell == "rnn":
+        # ---- RNN cell (JODIE): tanh(x W + h U + b), one gate ------------
+        s_meas = jnp.tanh(jnp.dot(x, w, preferred_element_type=jnp.float32)
+                          + jnp.dot(h, u, preferred_element_type=jnp.float32)
+                          + b)
+    else:
+        # ---- GRU gates: both matmuls back-to-back on the MXU ------------
+        gx = jnp.dot(x, w, preferred_element_type=jnp.float32) + b
+        gh = jnp.dot(h, u, preferred_element_type=jnp.float32)
+        d = h.shape[-1]
+        rx, zx, nx = gx[:, :d], gx[:, d:2 * d], gx[:, 2 * d:]
+        rh, zh, nh = gh[:, :d], gh[:, d:2 * d], gh[:, 2 * d:]
+        r = jax.nn.sigmoid(rx + rh)
+        z = jax.nn.sigmoid(zx + zh)
+        n = jnp.tanh(nx + r * nh)
+        s_meas = (1.0 - z) * h + z * n
     # ---- PRES predict (Eq. 7) -> correct (Eq. 8) -> delta rate (Eq. 9) ----
     s_pred = h + jnp.clip(scale * dmean, -clip, clip)
     fused = (1.0 - gamma) * s_pred + gamma * s_meas
@@ -66,11 +96,11 @@ def _gru_pres(x, h, w, u, b, dmean, scale, gamma, *, clip, delta_mode):
 def _memory_update_kernel(x_ref, h_ref, w_ref, u_ref, b_ref, dmean_ref,
                           scale_ref, gamma_ref, meas_ref, fused_ref,
                           delta_ref, *, clip, delta_mode):
-    s_meas, fused, delta = _gru_pres(
+    s_meas, fused, delta = _cell_pres(
         x_ref[...].astype(jnp.float32), h_ref[...].astype(jnp.float32),
         w_ref[...], u_ref[...], b_ref[...],
         dmean_ref[...].astype(jnp.float32), scale_ref[...], gamma_ref[0, 0],
-        clip=clip, delta_mode=delta_mode)
+        cell="gru", clip=clip, delta_mode=delta_mode)
     meas_ref[...] = s_meas.astype(meas_ref.dtype)
     fused_ref[...] = fused.astype(fused_ref.dtype)
     delta_ref[...] = delta.astype(delta_ref.dtype)
@@ -150,7 +180,7 @@ def _memory_update_table_kernel(g_ref, wi_ref, tab_in, x_ref, ok_ref, w_ref,
                                 u_ref, b_ref, dmean_ref, scale_ref,
                                 gamma_ref, tab_out, meas_ref, fused_ref,
                                 delta_ref, h_buf, f_buf, sems, *, n, d,
-                                block_m, clip, delta_mode):
+                                block_m, cell, clip, delta_mode):
     del tab_in  # the same HBM buffer as tab_out (input_output_aliases)
     c = h_buf.shape[0]
     base = pl.program_id(0) * block_m
@@ -171,10 +201,10 @@ def _memory_update_table_kernel(g_ref, wi_ref, tab_in, x_ref, ok_ref, w_ref,
     for j, k in copies:
         gather(j, k).wait()
     h = jnp.where(ok_ref[...] > 0, tiling.from_slabs(h_buf[...], d), 0.0)
-    s_meas, fused, delta = _gru_pres(
+    s_meas, fused, delta = _cell_pres(
         x_ref[...].astype(jnp.float32), h, w_ref[...], u_ref[...],
         b_ref[...], dmean_ref[...].astype(jnp.float32), scale_ref[...],
-        gamma_ref[0, 0], clip=clip, delta_mode=delta_mode)
+        gamma_ref[0, 0], cell=cell, clip=clip, delta_mode=delta_mode)
     for k, slab in enumerate(tiling.to_slabs(fused, c)):
         f_buf[k] = slab
     meas_ref[...] = s_meas.astype(meas_ref.dtype)
@@ -193,21 +223,22 @@ def _memory_update_table_kernel(g_ref, wi_ref, tab_in, x_ref, ok_ref, w_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "clip", "delta_mode",
-                                             "interpret"))
+                                             "interpret", "cell"))
 def _memory_update_table_pallas(table, last_t, x, gather_idx, write_idx,
                                 times, w, u, b, delta_mean, scale, gamma, *,
                                 block_m: int = 32, clip: float = 5.0,
                                 delta_mode: str = "innovation",
-                                interpret: bool = True):
+                                interpret: bool = True, cell: str = "gru"):
     """table: (N, D) memory, last_t: (N,), x: (M, Din) messages,
     gather_idx/write_idx: (M,) int32 row indices (N = masked-write drop
-    slot, N + 1 = all-zeros masked read), times: (M,); weights/PRES args
-    as in memory_update. Returns (new_table, new_last_t, s_meas, fused,
-    delta).
+    slot, N + 1 = all-zeros masked read), times: (M,); w: (Din, G*D),
+    u: (D, G*D), b: (G*D,) the memory cell's weights, G = GATES[cell]
+    (3 for the GRU, 1 for the RNN); PRES args as in memory_update.
+    Returns (new_table, new_last_t, s_meas, fused, delta).
 
     One pass over the M occurrences in tiles of `block_m`: each grid step
     DMAs its tile's rows from the HBM-resident (aliased) table into VMEM,
-    runs the fused GRU+PRES math, and DMAs the selected fused rows back,
+    runs the fused cell+PRES math, and DMAs the selected fused rows back,
     updating the table in place (through the 128-lane slab view of
     kernels/tiling.py). The per-occurrence
     timestamps scatter into last_t outside the kernel (one scalar per row;
@@ -223,8 +254,10 @@ def _memory_update_table_pallas(table, last_t, x, gather_idx, write_idx,
     gathers, so prefetching tile T + 1's gathers ahead of tile T's writes
     would stay hazard-free too. The oracle gathers everything up front, so
     any violation shows up as a parity failure, not silent corruption."""
+    _check_cell(cell)
     n, d = table.shape
     m, din = x.shape
+    gd = GATES[cell] * d
     c = tiling.n_slabs(d)
     gidx, widx, x, dmean, sc = tiling.pad_rows(
         block_m, gather_idx.astype(jnp.int32), write_idx.astype(jnp.int32),
@@ -244,9 +277,9 @@ def _memory_update_table_pallas(table, last_t, x, gather_idx, write_idx,
             pl.BlockSpec(memory_space=pl.ANY),           # table (HBM, alias)
             pl.BlockSpec((block_m, din), row),           # x
             pl.BlockSpec((block_m, 1), row),             # gather-valid
-            pl.BlockSpec((din, 3 * d), whole),           # w
-            pl.BlockSpec((d, 3 * d), whole),             # u
-            pl.BlockSpec((1, 3 * d), whole),             # b
+            pl.BlockSpec((din, gd), whole),              # w
+            pl.BlockSpec((d, gd), whole),                # u
+            pl.BlockSpec((1, gd), whole),                # b
             pl.BlockSpec((block_m, d), row),             # dmean
             pl.BlockSpec((block_m, 1), row),             # scale
             tiling.SMEM_SCALAR,                          # gamma
@@ -264,7 +297,8 @@ def _memory_update_table_pallas(table, last_t, x, gather_idx, write_idx,
         ])
     outs = pl.pallas_call(
         functools.partial(_memory_update_table_kernel, n=n, d=d,
-                          block_m=block_m, clip=clip, delta_mode=delta_mode),
+                          block_m=block_m, cell=cell, clip=clip,
+                          delta_mode=delta_mode),
         grid_spec=grid_spec,
         out_shape=[
             tiling.out_struct((n * c, tiling.LANES), jnp.float32, *like),
@@ -274,8 +308,8 @@ def _memory_update_table_pallas(table, last_t, x, gather_idx, write_idx,
         # 2 = table -> output 0 (in-place table)
         input_output_aliases={2: 0},
         interpret=interpret,
-        name="memory_update_table",
-    )(gidx, widx, tiling.slab_view(table), x, ok, w, u, b.reshape(1, 3 * d),
+        name=table_kernel_name(cell),
+    )(gidx, widx, tiling.slab_view(table), x, ok, w, u, b.reshape(1, gd),
       dmean, sc, jnp.reshape(gamma.astype(jnp.float32), (1, 1)))
     new_tab = tiling.from_slab_view(outs[0], n, d, table.dtype)
     new_lt = last_t.at[write_idx].set(times.astype(last_t.dtype),
@@ -285,7 +319,7 @@ def _memory_update_table_pallas(table, last_t, x, gather_idx, write_idx,
 
 @functools.lru_cache(maxsize=None)
 def _diff_memory_update_table(block_m: int, clip: float, delta_mode: str,
-                              interpret: bool):
+                              interpret: bool, cell: str):
     """Pallas forward, oracle backward. The int32 index args get float0
     cotangents from jax.vjp of the ref (same convention as neighbor_attn's
     bool mask); the table cotangent flows through the oracle's
@@ -294,20 +328,21 @@ def _diff_memory_update_table(block_m: int, clip: float, delta_mode: str,
     return autodiff.oracle_vjp(
         functools.partial(_memory_update_table_pallas, block_m=block_m,
                           clip=clip, delta_mode=delta_mode,
-                          interpret=interpret),
+                          interpret=interpret, cell=cell),
         functools.partial(ref.memory_update_table_ref, clip=clip,
-                          delta_mode=delta_mode))
+                          delta_mode=delta_mode, cell=cell))
 
 
 def memory_update_table(table, last_t, x, gather_idx, write_idx, times,
                         w, u, b, delta_mean, scale, gamma, *,
                         block_m: int = 32, clip: float = 5.0,
                         delta_mode: str = "innovation",
-                        interpret: bool = True):
-    """Differentiable fused gather -> memory_update -> scatter-back pass
-    over the touched rows — see _memory_update_table_pallas and
-    docs/KERNELS.md §memory_update_table."""
-    return _diff_memory_update_table(block_m, clip, delta_mode, interpret)(
+                        interpret: bool = True, cell: str = "gru"):
+    """Differentiable fused gather -> memory cell + PRES -> scatter-back
+    pass over the touched rows, for the GRU or the RNN cell — see
+    _memory_update_table_pallas and docs/KERNELS.md §memory_update_table."""
+    return _diff_memory_update_table(block_m, clip, delta_mode, interpret,
+                                     cell)(
         table, last_t, x, gather_idx, write_idx, times, w, u, b,
         delta_mean, scale, gamma)
 

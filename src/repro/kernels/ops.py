@@ -116,6 +116,10 @@ class KernelSpec:
     # kwargs only the Pallas impl understands (stripped, with the block
     # sizes and `interpret`, before the oracle is called)
     impl_only: tuple[str, ...] = ()
+    # for a kernel with several forms chosen by a static kwarg: the form's
+    # name from the call's kwargs — the name its pallas_call carries in a
+    # profiler trace, and its key in the dispatch log
+    form_name: Callable[[Mapping[str, Any]], str] | None = None
 
 
 REGISTRY: dict[str, KernelSpec] = {}
@@ -149,8 +153,9 @@ _register(KernelSpec(
     name="memory_update_table",
     impl=_mu.memory_update_table, ref=ref.memory_update_table_ref,
     blocks={"block_m": 32},
-    doc="touched-row gather + fused GRU/PRES update + table scatter-back "
-        "in ONE pass (aliased (N, D) table, docs/KERNELS.md)"))
+    doc="touched-row gather + fused GRU or RNN cell + PRES update + table "
+        "scatter-back in ONE pass (aliased (N, D) table, docs/KERNELS.md)",
+    form_name=lambda kw: _mu.table_kernel_name(kw.get("cell", "gru"))))
 _register(KernelSpec(
     name="link_score", impl=_ls.link_score, ref=ref.link_score_ref,
     blocks={"block_b": 32, "block_i": 128},
@@ -194,7 +199,9 @@ def _oracle_fn(name: str, kw_items: tuple) -> Callable:
 
 
 # Kernel-dispatch log (docs/OBSERVABILITY.md §Kernel-dispatch table):
-# (kernel, resolved mode) -> dispatch-call count. dispatch() runs at TRACE
+# (kernel form, resolved mode) -> dispatch-call count; a kernel's form is
+# its registry name unless the spec names its forms (`form_name`).
+# dispatch() runs at TRACE
 # time — once per jit compilation, not per executed step — so the log is a
 # per-process record of which execution-policy branch each kernel actually
 # took, at zero steady-state cost. The obs sink stamps it into every
@@ -240,7 +247,8 @@ def dispatch(name: str, *args, mode: str | None = None, **kw):
         mode = _backend_default()
     for k, v in {**dict(spec.blocks), **dict(sel_blocks)}.items():
         kw.setdefault(k, v)
-    DISPATCH_LOG[(name, mode)] += 1
+    DISPATCH_LOG[(spec.form_name(kw) if spec.form_name else name,
+                  mode)] += 1
     if mode == "oracle":
         strip = set(spec.blocks) | set(spec.impl_only) | {"interpret"}
         okw = tuple(sorted((k, v) for k, v in kw.items() if k not in strip))
@@ -279,9 +287,10 @@ def memory_update(x, h, w, u, b, delta_mean, scale, gamma, **kw):
 def memory_update_table(table, last_t, x, gather_idx, write_idx, times,
                         w, u, b, delta_mean, scale, gamma, **kw):
     """Fused touched-row pass: gather h from `table` at gather_idx, run the
-    memory_update math, scatter the fused rows back at write_idx (row
-    n_nodes = masked-write dump, n_nodes+1 = masked-read zeros source).
-    Returns (new_table, new_last_t, s_meas, fused, delta)."""
+    memory cell (`cell=` "gru" or "rnn") and the PRES filter, scatter the
+    fused rows back at write_idx (row n_nodes = masked-write dump,
+    n_nodes+1 = masked-read zeros source). Returns (new_table, new_last_t,
+    s_meas, fused, delta)."""
     return dispatch("memory_update_table", table, last_t, x, gather_idx,
                     write_idx, times, w, u, b, delta_mean, scale, gamma, **kw)
 

@@ -18,6 +18,14 @@ def gru_cell_ref(x, h, w, u, b):
     return (1 - z) * h + z * n
 
 
+def rnn_cell_ref(x, h, w, u, b):
+    """x: (M, Din), h: (M, D), w: (Din, D), u: (D, D), b: (D,)."""
+    return jnp.tanh(x @ w + h @ u + b)
+
+
+CELL_REFS = {"gru": gru_cell_ref, "rnn": rnn_cell_ref}
+
+
 def pres_predict_ref(s_prev, delta_mean, dt, clip=5.0):
     """Eq. 7 extrapolation fill: s_prev + clip(dt * delta_mean)."""
     return s_prev + jnp.clip(dt[:, None] * delta_mean, -clip, clip)
@@ -36,11 +44,12 @@ def pres_filter_ref(s_prev, s_meas, delta_mean, dt, gamma, clip=5.0,
 
 
 def memory_update_ref(x, h, w, u, b, delta_mean, scale, gamma, clip=5.0,
-                      delta_mode="innovation"):
-    """Fused MEMORY maintenance over the touched rows: GRU transition
-    (measurement) -> Eq. 7 predict -> Eq. 8 correct -> delta rate.
-    Returns (s_meas, fused, delta_rate), each (M, D)."""
-    s_meas = gru_cell_ref(x, h, w, u, b)
+                      delta_mode="innovation", cell="gru"):
+    """Fused MEMORY maintenance over the touched rows: the memory cell's
+    transition (measurement; `cell` "gru" or "rnn") -> Eq. 7 predict ->
+    Eq. 8 correct -> delta rate. Returns (s_meas, fused, delta_rate), each
+    (M, D)."""
+    s_meas = CELL_REFS[cell](x, h, w, u, b)
     fused, delta = pres_filter_ref(h, s_meas, delta_mean, scale, gamma,
                                    clip=clip, delta_mode=delta_mode)
     return s_meas, fused, delta
@@ -48,7 +57,7 @@ def memory_update_ref(x, h, w, u, b, delta_mean, scale, gamma, clip=5.0,
 
 def memory_update_table_ref(table, last_t, x, gather_idx, write_idx, times,
                             w, u, b, delta_mean, scale, gamma, clip=5.0,
-                            delta_mode="innovation"):
+                            delta_mode="innovation", cell="gru"):
     """Fused touched-row pass over the WHOLE memory table: gather the
     previous rows at gather_idx, run memory_update_ref on them, scatter the
     fused rows (and their timestamps) back at write_idx.
@@ -74,7 +83,8 @@ def memory_update_table_ref(table, last_t, x, gather_idx, write_idx, times,
                   0.0).astype(jnp.float32)
     s_meas, fused, delta = memory_update_ref(x, h, w, u, b, delta_mean,
                                              scale, gamma, clip=clip,
-                                             delta_mode=delta_mode)
+                                             delta_mode=delta_mode,
+                                             cell=cell)
     new_tab = table.at[write_idx].set(fused.astype(table.dtype),
                                       mode="drop")
     new_lt = last_t.at[write_idx].set(times.astype(last_t.dtype),

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from repro.graph import datasets
 from repro.graph.datasets import SPECS
 from repro.launch import compile_cache
-from repro.models.mdgnn import MDGNNConfig, init_params, init_state
+from repro.models.mdgnn import (VARIANT_MEMORY_CELLS, MDGNNConfig,
+                                init_params, init_state)
 from repro.serve import MicroBatcher, ServeEngine, replay
 
 
@@ -40,7 +41,9 @@ def serve_mdgnn(args):
         spec = SPECS[args.dataset]
         stream = datasets.get_dataset(args.dataset, args.seed)
         dst_range = (spec.n_users, spec.n_users + spec.n_items)
-    cfg = MDGNNConfig(variant=args.model, n_nodes=stream.num_nodes,
+    cfg = MDGNNConfig(variant=args.model,
+                      memory_cell=VARIANT_MEMORY_CELLS[args.model],
+                      n_nodes=stream.num_nodes,
                       d_edge=stream.feat_dim, d_mem=args.d_mem,
                       d_msg=args.d_mem, d_embed=args.d_mem,
                       n_layers=args.n_layers, use_pres=args.pres,

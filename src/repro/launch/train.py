@@ -18,7 +18,8 @@ import numpy as np
 from repro.graph import datasets
 from repro.graph.datasets import SPECS
 from repro.launch import compile_cache
-from repro.models.mdgnn import MDGNNConfig, init_params, init_state
+from repro.models.mdgnn import (VARIANT_MEMORY_CELLS, MDGNNConfig,
+                                init_params, init_state)
 from repro.optim import adamw
 from repro.train import loop, pipeline, scan
 from repro.checkpoint import save_checkpoint
@@ -47,7 +48,7 @@ def main(argv=None):
     ap.add_argument("--d-mem", type=int, default=100)
     ap.add_argument("--n-layers", type=int, default=1,
                     help="embedding depth: hops of temporal attention (tgn) "
-                         "or stacked layers (jodie/apan)")
+                         "or stacked mailbox layers (apan); jodie has one")
     ap.add_argument("--n-heads", type=int, default=2,
                     help="attention heads in the embedding stack")
     ap.add_argument("--seed", type=int, default=0)
@@ -57,9 +58,10 @@ def main(argv=None):
                          "(M*K^d rows per hop) instead of the deduplicated "
                          "unique tables (docs/DESIGN.md §Embedding stack)")
     ap.add_argument("--use-kernels", action="store_true",
-                    help="route the full memory-maintenance step (fused GRU"
-                         " + PRES filter kernel under --pres, gru_cell "
-                         "otherwise) and the embedding attention through "
+                    help="route the full memory-maintenance step (the "
+                         "fused memory cell + PRES filter kernel under "
+                         "--pres, gru_cell otherwise) and the embedding "
+                         "attention through "
                          "the registered Pallas kernels (docs/KERNELS.md)")
     ap.add_argument("--kernels-mode", default="auto",
                     choices=["auto", "compiled", "interpret", "oracle"],
@@ -129,7 +131,8 @@ def main(argv=None):
 
     train_s, val_s, test_s = stream.chronological_split()
     cfg = MDGNNConfig(
-        variant=args.model, n_nodes=stream.num_nodes, d_edge=stream.feat_dim,
+        variant=args.model, memory_cell=VARIANT_MEMORY_CELLS[args.model],
+        n_nodes=stream.num_nodes, d_edge=stream.feat_dim,
         d_mem=args.d_mem, d_msg=args.d_mem, d_embed=args.d_mem,
         n_layers=args.n_layers, n_heads=args.n_heads,
         use_pres=args.pres, beta=args.beta, delta_mode=args.delta_mode,

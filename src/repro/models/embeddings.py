@@ -8,8 +8,9 @@ Each entry implements the paper's EMBEDDING step for one model family:
                    layer l-1 embeddings of its temporal neighbours, with
                    genuine multi-head attention and an optional Pallas
                    kernel inner loop (kernels/ops.py::neighbor_attn)
-    jodie_proj   — time-projection embedding h = (1 + dt*w) . s with
-                   optional extra projection layers
+    jodie_proj   — JODIE's time projection as TGL's JODIETimeEmbedding
+                   computes it: h = s . (1 + (D w + b)), D = (t - t_last) /
+                   (t + 1), on the identity GNN (one layer, d_embed = d_mem)
     apan_mailbox — stacked attention over a per-node mailbox of
                    propagated messages
 
@@ -22,9 +23,10 @@ pair of pure functions:
 Depth semantics (`cfg.n_layers`): for tgn_attn each extra layer is an extra
 HOP — the k-hop frontier expansion in `core/batching.py::expand_frontiers`
 keeps every level a static (M, K**l) gather so the whole stack jits. For
-jodie/apan, which have no recursive neighbourhood, extra layers stack extra
-projection / mailbox-attention layers on the same inputs. All three reduce
-bit-exactly to the historical single-layer path at n_layers=1.
+apan, which has no recursive neighbourhood, extra layers stack extra
+mailbox-attention layers on the same inputs; both reduce bit-exactly to
+the historical single-layer path at n_layers=1. JODIE's projection has one
+published form and no depth: jodie_init refuses n_layers != 1.
 """
 from __future__ import annotations
 
@@ -273,26 +275,33 @@ register("tgn_attn", tgn_init, tgn_apply)
 
 
 def jodie_init(emb, cfg):
-    if cfg.n_layers < 1:
-        raise ValueError(f"n_layers={cfg.n_layers} must be >= 1")
+    """TGL's `JODIETimeEmbedding`: a `NormalLinear(1, d_mem)`, weight and
+    bias both drawn N(0, 1/sqrt(1)). The weight is stored (1, d_mem), the
+    (in, out) layout of every linear map here. The embedding is the
+    projected memory row itself (TGL's identity GNN), so it has one layer
+    and d_embed = d_mem; other settings have no source and raise."""
+    if cfg.n_layers != 1:
+        raise ValueError(f"jodie's embedding has one layer; n_layers="
+                         f"{cfg.n_layers} has no published form")
+    if cfg.d_embed != cfg.d_mem:
+        raise ValueError(f"jodie's embedding is the projected memory row: "
+                         f"d_embed={cfg.d_embed} must equal "
+                         f"d_mem={cfg.d_mem}")
     l0 = emb.sub(_layer_name(0))
-    l0.add("w_proj", (1, cfg.d_mem), (None, "embed"))
-    l0.add("w_out", (cfg.d_mem, cfg.d_embed), ("embed", "mlp"))
-    for l in range(1, cfg.n_layers):
-        lb = emb.sub(_layer_name(l))
-        lb.add("w", (cfg.d_embed, cfg.d_embed), ("embed", "mlp"))
+    l0.add("w", (1, cfg.d_mem), (None, "embed"), scale=1.0)
+    l0.add("b", (cfg.d_mem,), ("embed",), scale=1.0)
 
 
 def jodie_apply(params, cfg, state, nodes, t_query):
+    """h = s . (1 + (D w + b)) with s the node's memory row and
+    D = (t_query - t_last) / (t_query + 1) its elapsed time, normalised by
+    the query time as TGL does, in float32."""
     mem = state["memory"]
     s = annotate.events(mem.mem[nodes]).astype(jnp.float32)
     l0 = params["emb"][_layer_name(0)]
-    dt = (t_query - annotate.events(mem.last_update[nodes]))[:, None]
-    proj = s * (1.0 + dt * l0["w_proj"][0])
-    h = jnp.tanh(proj @ l0["w_out"])
-    for l in range(1, cfg.n_layers):
-        h = jnp.tanh(h @ params["emb"][_layer_name(l)]["w"])
-    return h
+    t_last = annotate.events(mem.last_update[nodes])
+    dt = ((t_query - t_last) / (t_query + 1.0))[:, None]
+    return s * (1.0 + (dt * l0["w"][0] + l0["b"]))
 
 
 register("jodie_proj", jodie_init, jodie_apply)

@@ -11,8 +11,12 @@ resolved through the pluggable registry in `repro.models.embeddings`
             ring buffers (cfg.n_layers hops, cfg.n_heads heads; the inner
             attention loop routes through the Pallas kernel
             `kernels/ops.py::neighbor_attn` when cfg.use_kernels)
-    JODIE — time-projection embedding  h = (1 + dt*w) . s
+    JODIE — RNN memory cell and TGL's time projection
+            h = s . (1 + (D w + b)), D = (t - t_last) / (t + 1)
     APAN  — stacked attention over a per-node mailbox of propagated messages
+
+`VARIANT_MEMORY_CELLS` gives each variant its published memory cell, which
+the entry points (launch/train.py, launch/serve.py) build.
 """
 from __future__ import annotations
 
@@ -43,10 +47,11 @@ class MDGNNConfig:
     d_embed: int = 100
     n_neighbors: int = 10
     n_layers: int = 1            # EMBEDDING depth: hops for tgn, stacked
-                                 # layers for jodie/apan (docs/DESIGN.md)
+                                 # layers for apan, 1 for jodie
+                                 # (docs/DESIGN.md)
     n_heads: int = 2
     mailbox_size: int = 10       # APAN
-    memory_cell: str = "gru"
+    memory_cell: str = "gru"     # gru | rnn (VARIANT_MEMORY_CELLS)
     aggregator: str = "last"     # last | mean  (per-node message reduction)
     # PRES
     use_pres: bool = False       # prediction-correction filter (Sec. 5.1)
@@ -118,6 +123,12 @@ class MDGNNConfig:
     # additional host syncs, so the knob is safe to leave on in perf runs
     # (the CI overhead gate pins >= 0.9x events/sec).
     obs_metrics: bool = False
+
+
+# The memory cell each variant is published with: TGN's GRU (TGL
+# config/TGN.yml), JODIE's RNN (Kumar et al. 2019; TGL config/JODIE.yml
+# `memory_update: rnn`), APAN's GRU as this repository builds it.
+VARIANT_MEMORY_CELLS = {"tgn": "gru", "jodie": "rnn", "apan": "gru"}
 
 
 # ---------------------------------------------------------------------------

@@ -82,10 +82,21 @@ def _apply_pres(params, cfg, mem2, info, pres_state):
     return MemoryState(mem=table, last_update=mem2.last_update), fused, delta
 
 
+def fused_memory_path(cfg: MDGNNConfig, gru_fn=None) -> bool:
+    """Whether the MEMORY stage runs as the fused "memory_update_table"
+    kernel: with kernels and PRES on, for either memory cell (the kernel
+    takes the cell as a static choice), unless the caller overrode the
+    memory cell with a `gru_fn` of its own. Shared by the single-device
+    and the sharded (repro.train.routing) stages."""
+    return (cfg.use_kernels and cfg.use_pres
+            and gru_fn in (None, modules.kernel_memory_cell(cfg)))
+
+
 def _fused_memory_update(params, cfg, state, prev_batch: EventBatch):
     """The whole memory-maintenance step in ONE fused pass over the touched
-    rows (registry kernel "memory_update_table"): the memory-row gather,
-    the GRU gates, Eq. 7 predict, Eq. 8 correct, the delta-rate statistic
+    rows (registry kernel "memory_update_table", in its form for
+    cfg.memory_cell): the memory-row gather, the GRU gates or the RNN
+    cell, Eq. 7 predict, Eq. 8 correct, the delta-rate statistic
     AND the table/timestamp scatter-back, per occurrence, through an
     aliased (N, D) table (docs/KERNELS.md §memory_update_table). Only the
     GMM mixture-mean gather stays outside.
@@ -122,7 +133,8 @@ def _fused_memory_update(params, cfg, state, prev_batch: EventBatch):
         mem.mem, mem.last_update, msgs[order], gidx, widx, times[order],
         params["mem"]["w"], params["mem"]["u"], params["mem"]["b"],
         dmean[order], scale[order], gamma,
-        clip=cfg.pres_clip, delta_mode=cfg.delta_mode, mode=cfg.kernels_mode)
+        clip=cfg.pres_clip, delta_mode=cfg.delta_mode, cell=cfg.memory_cell,
+        mode=cfg.kernels_mode)
     # same compact-update boundary the cell path puts on its new_rows
     info["s_meas"] = annotate.compact(s_meas[inv])
     fused = annotate.compact(fused[inv])
@@ -135,10 +147,14 @@ def memory_and_pres(params, cfg: MDGNNConfig, state, prev_batch: EventBatch,
     """MEMORY stage + PRES fusion, shared by the sequential, eval and
     pipelined steps, with kernel routing (docs/KERNELS.md §Dispatch):
 
-    * use_kernels + PRES + GRU  -> the fused "memory_update" kernel
-    * use_kernels otherwise     -> "gru_cell" (via gru_fn) and/or
-                                   "pres_filter" kernels separately
+    * use_kernels + PRES        -> the fused "memory_update_table"
+                                   kernel, GRU or RNN form
+    * use_kernels, no PRES      -> "gru_cell" (via gru_fn) for the GRU,
+                                   the jnp cell for the RNN
     * no kernels                -> pure-jnp cell + pres.predict/correct
+
+    ("pres_filter" stays for a caller that overrides the cell with a
+    gru_fn of its own under PRES.)
 
     Returns (mem_state, info, fused_rows, deltas); without PRES the fused
     rows are the raw measurements and the deltas are zero.
@@ -155,8 +171,7 @@ def memory_and_pres(params, cfg: MDGNNConfig, state, prev_batch: EventBatch,
         from repro.train import routing
         return routing.sharded_memory_and_pres(params, cfg, state,
                                                prev_batch, gru_fn=gru_fn)
-    if (cfg.use_kernels and cfg.use_pres and cfg.memory_cell == "gru"
-            and gru_fn in (None, modules.kernel_memory_cell(cfg))):
+    if fused_memory_path(cfg, gru_fn):
         return _fused_memory_update(params, cfg, state, prev_batch)
     mem2, info = mdgnn.memory_update(params, cfg, state["memory"],
                                      prev_batch, gru_fn=gru_fn,
@@ -359,10 +374,10 @@ def make_train_step(cfg: MDGNNConfig, opt, gru_fn=None):
 
     cfg.use_kernels routes the FULL memory-maintenance path plus the
     embedding attention through the registered Pallas kernels
-    (docs/KERNELS.md): under PRES+GRU the whole update fuses into the
-    "memory_update" kernel; otherwise the memory cell ("gru_cell", resolved
-    by modules.kernel_memory_cell) and the PRES filter ("pres_filter")
-    route separately, and the neighbour attention resolves inside
+    (docs/KERNELS.md): under PRES the whole update fuses into the
+    "memory_update_table" kernel (GRU or RNN form); without PRES the GRU
+    cell routes through "gru_cell" (resolved by
+    modules.kernel_memory_cell), and the neighbour attention resolves inside
     embed_nodes (docs/DESIGN.md §Embedding stack). Pass gru_fn explicitly
     to override the memory cell only.
 

@@ -294,8 +294,8 @@ def sharded_memory_and_pres(params, cfg: MDGNNConfig, state, prev_batch,
         prev_batch, n)
     m_slice = nodes.shape[0] // n                     # occurrences per shard
     budget = cfg.shard_budget or m_slice              # default: overflow-free
-    use_fused = (cfg.use_kernels and cfg.use_pres and cfg.memory_cell == "gru"
-                 and gru_fn in (None, modules.kernel_memory_cell(cfg)))
+    from repro.train import loop
+    use_fused = loop.fused_memory_path(cfg, gru_fn)
     # Per-bucket GMM mixture-mean table, elementwise over the sharded
     # trackers (stays sharded, no communication): the request gather below
     # serves dmean rows from it exactly like memory rows.
@@ -383,7 +383,7 @@ def sharded_memory_and_pres(params, cfg: MDGNNConfig, state, prev_batch,
                 params["mem"]["w"], params["mem"]["u"], params["mem"]["b"],
                 r_dmean[order], scale[order], gamma,
                 clip=cfg.pres_clip, delta_mode=cfg.delta_mode,
-                mode=cfg.kernels_mode)
+                cell=cfg.memory_cell, mode=cfg.kernels_mode)
             s_meas, fused, delta = s_meas[inv], fused[inv], delta[inv]
         else:
             _, cell = modules.MEMORY_CELLS[cfg.memory_cell]

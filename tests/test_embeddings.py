@@ -305,3 +305,54 @@ def test_kernel_and_reference_losses_agree_at_depth_2():
                           jax.tree.map(jnp.copy, state), prev, pos, neg)
         losses.append(float(m["loss"]))
     np.testing.assert_allclose(losses[0], losses[1], rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# jodie_proj — TGL's JODIETimeEmbedding
+# ---------------------------------------------------------------------------
+
+
+def test_jodie_projection_at_a_hand_computed_elapsed_time():
+    """h = s . (1 + (D w + b)), D = (t - t_last) / (t + 1): node 3, last
+    updated at 4 and queried at 9, has D = 0.5; node 5, never updated
+    (t_last 0) and queried at 3, has D = 0.75."""
+    cfg = _cfg("jodie")
+    params, _ = mdgnn.init_params(jax.random.PRNGKey(7), cfg)
+    state = mdgnn.init_state(cfg)
+    rng = np.random.default_rng(7)
+    table = rng.normal(size=(cfg.n_nodes, cfg.d_mem)).astype(np.float32)
+    last = np.zeros(cfg.n_nodes, np.float32)
+    last[3] = 4.0
+    state = dict(state, memory=modules.MemoryState(
+        mem=jnp.asarray(table), last_update=jnp.asarray(last)))
+    h = mdgnn.embed_nodes(params, cfg, state, jnp.asarray([3, 5]),
+                          jnp.asarray([9.0, 3.0]))
+    w = np.asarray(params["emb"]["l0"]["w"])[0]
+    b = np.asarray(params["emb"]["l0"]["b"])
+    want = np.stack([table[3] * (1.0 + (0.5 * w + b)),
+                     table[5] * (1.0 + (0.75 * w + b))])
+    np.testing.assert_allclose(np.asarray(h), want, rtol=1e-6)
+
+
+def test_jodie_projection_is_tgls_normal_linear():
+    """One weight and one bias of width d_mem, both drawn N(0, 1) as TGL's
+    NormalLinear(1, d) draws them."""
+    cfg = dataclasses.replace(_cfg("jodie"), d_mem=4096, d_embed=4096)
+    params, _ = mdgnn.init_params(jax.random.PRNGKey(8), cfg)
+    l0 = params["emb"]["l0"]
+    assert set(params["emb"]) == {"l0"} and set(l0) == {"w", "b"}
+    assert l0["w"].shape == (1, 4096) and l0["b"].shape == (4096,)
+    for v in (l0["w"], l0["b"]):
+        assert abs(float(jnp.std(v)) - 1.0) < 0.05
+        assert abs(float(jnp.mean(v))) < 0.05
+
+
+@pytest.mark.parametrize("kw,match", [(dict(n_layers=2), "one layer"),
+                                      (dict(n_layers=3), "one layer"),
+                                      (dict(d_embed=8), "d_embed")])
+def test_jodie_settings_without_a_published_form_raise(kw, match):
+    """Stacked projection layers and an embedding wider or narrower than
+    the memory have no source: building them raises."""
+    with pytest.raises(ValueError, match=match):
+        mdgnn.init_params(jax.random.PRNGKey(0),
+                          dataclasses.replace(_cfg("jodie"), **kw))

@@ -41,8 +41,9 @@ def test_empirical_coherence_during_training():
 
     def loss_at(params, mem_rows):
         """decoder loss of batch-1 events at the given endpoint rows."""
-        e = params["emb"]["l0"]   # jodie_proj layer-0 params (registry layout)
-        h = jnp.tanh((mem_rows * 1.0) @ e["w_out"])
+        e = params["emb"]["l0"]   # jodie_proj params (registry layout)
+        # the time projection at zero elapsed time: h = s . (1 + b)
+        h = mem_rows * (1.0 + e["b"])
         hs, hd = h[: ev.size], h[ev.size:]
         logits = mdgnn.link_logits(params, hs, hd)
         return jnp.mean(jax.nn.softplus(-logits))

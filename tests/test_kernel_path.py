@@ -1,9 +1,9 @@
 """End-to-end kernel-execution-layer parity (docs/KERNELS.md §Dispatch).
 
 With cfg.use_kernels the training/eval/pipelined steps route the full
-memory-maintenance path through the registered Pallas kernels (fused
-memory_update under PRES+GRU, gru_cell / pres_filter separately otherwise,
-pres_predict for the pipeline staleness fill). In interpret mode those
+memory-maintenance path through the registered Pallas kernels (the fused
+memory_update_table under PRES, in its GRU or RNN form; gru_cell for the
+GRU without PRES; pres_predict for the pipeline staleness fill). In interpret mode those
 kernels are the same computation as the pure-jnp path, so one training step
 must match it within 1e-5 for the params, the memory table and the logits —
 the acceptance contract for the kernel layer.
@@ -63,7 +63,7 @@ def _assert_tree_close(a, b, atol=1e-5):
     dict(memory_cell="gru", use_pres=True, delta_mode="innovation"),  # fused
     dict(memory_cell="gru", use_pres=True, pres_scale="time"),        # fused
     dict(memory_cell="gru", use_pres=False),          # gru_cell kernel only
-    dict(memory_cell="rnn", use_pres=True),           # pres_filter kernel only
+    dict(memory_cell="rnn", use_pres=True),           # fused, RNN form
 ])
 def test_train_step_kernel_parity(tiny_stream, tiny_spec, case):
     """The acceptance contract: one (here: two, to exercise warm trackers)
@@ -208,3 +208,23 @@ def test_kernel_memory_cell_resolver(tiny_stream):
     fn = modules.kernel_memory_cell(_cfg(tiny_stream, True))
     from repro.kernels import ops as kops
     assert fn is kops.gru_cell_params
+
+
+def test_jodie_entry_point_runs_the_rnn_table_kernel(tmp_path):
+    """`launch/train.py --model jodie --pres --use-kernels` builds JODIE's
+    published RNN memory cell and runs its whole memory stage as the fused
+    table kernel's RNN form: the dispatch log holds
+    `memory_update_table_rnn` and neither the split path's kernels
+    (`pres_filter`, `gru_cell`) nor the GRU form."""
+    import json
+    from repro.kernels import ops as kops
+    from repro.launch import train as train_cli
+    out = tmp_path / "run.json"
+    kops.reset_dispatch_log()
+    train_cli.main(["--dataset", "wiki-small", "--model", "jodie", "--pres",
+                    "--use-kernels", "--epochs", "1", "--batch-size", "2000",
+                    "--d-mem", "8", "--json-out", str(out)])
+    log = kops.dispatch_log()
+    assert "memory_update_table_rnn" in log, log
+    assert not {"pres_filter", "gru_cell", "memory_update_table"} & set(log)
+    assert json.loads(out.read_text())["config"]["memory_cell"] == "rnn"

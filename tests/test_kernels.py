@@ -219,11 +219,12 @@ def test_memory_update_matches_composed_kernels():
 # ---------------------------------------------------------------------------
 
 
-def _memory_update_table_args(rng, n, m, d, pad_frac=0.2):
+def _memory_update_table_args(rng, n, m, d, pad_frac=0.2, gates=3):
     """Args in the kernel's required occurrence order (the layout
     mdgnn.occurrence_order produces): valid occurrences grouped by node,
     each group's last occurrence selected (written), masked occurrences
-    at the end gathering the all-zeros row n + 1."""
+    at the end gathering the all-zeros row n + 1. `gates`: 3 for the GRU
+    cell's weights, 1 for the RNN's."""
     n_valid = m - int(m * pad_frac)
     nodes = np.sort(rng.integers(0, n, size=n_valid))
     last = np.r_[nodes[:-1] != nodes[1:], True]
@@ -234,9 +235,9 @@ def _memory_update_table_args(rng, n, m, d, pad_frac=0.2):
             jnp.asarray(rng.normal(size=(m, d)), jnp.float32),   # x
             jnp.asarray(gidx, jnp.int32), jnp.asarray(widx, jnp.int32),
             jnp.abs(jnp.asarray(rng.normal(size=(m,)), jnp.float32)),  # times
-            jnp.asarray(rng.normal(size=(d, 3 * d)) * 0.1, jnp.float32),
-            jnp.asarray(rng.normal(size=(d, 3 * d)) * 0.1, jnp.float32),
-            jnp.asarray(rng.normal(size=(3 * d,)) * 0.01, jnp.float32),
+            jnp.asarray(rng.normal(size=(d, gates * d)) * 0.1, jnp.float32),
+            jnp.asarray(rng.normal(size=(d, gates * d)) * 0.1, jnp.float32),
+            jnp.asarray(rng.normal(size=(gates * d,)) * 0.01, jnp.float32),
             jnp.asarray(rng.normal(size=(m, d)) * 0.01, jnp.float32),
             jnp.abs(jnp.asarray(rng.normal(size=(m,)), jnp.float32)),
             jnp.asarray(0.4, jnp.float32))                       # gamma
@@ -322,6 +323,85 @@ def test_memory_update_table_gradients_match_oracle(delta_mode):
     gr = jax.grad(loss(ref.memory_update_table_ref), argnums=argnums)(*args)
     for a, b in zip(gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+def _split_rnn_path(args, clip, delta_mode):
+    """The unfused jnp chain the RNN form replaces: gather the previous
+    rows (masked occurrences read zeros), the program's RNN cell
+    (models/modules.py::rnn_cell), PRES predict -> correct -> delta rate,
+    and the last-occurrence scatter (models/mdgnn.py::scatter_rows)."""
+    from repro.models import mdgnn, modules
+    (table, last_t, x, gidx, widx, times, w, u, b, dm, scale, gamma) = args
+    n = table.shape[0]
+    h = jnp.where((gidx < n)[:, None], table[jnp.minimum(gidx, n - 1)], 0.0)
+    s_meas = modules.rnn_cell({"w": w, "u": u, "b": b}, x, h)
+    s_pred = h + jnp.clip(scale[:, None] * dm, -clip, clip)
+    fused = (1.0 - gamma) * s_pred + gamma * s_meas
+    base = s_pred if delta_mode == "innovation" else h
+    delta = (fused - base) / jnp.maximum(scale, 1.0)[:, None]
+    return (mdgnn.scatter_rows(table, widx, fused),
+            mdgnn.scatter_rows(last_t, widx, times), s_meas, fused, delta)
+
+
+@pytest.mark.parametrize("n,m", [(20, 1), (50, 64), (300, 200)])
+@pytest.mark.parametrize("delta_mode", ["innovation", "transition"])
+def test_memory_update_table_rnn_matches_split_path(n, m, delta_mode):
+    """The RNN form (interpret mode) against the split jnp path, with
+    nodes repeated within the batch (n=50 < m=64 forces repeats) and
+    masked occurrences (a fifth of them)."""
+    rng = np.random.default_rng(7 * n + m)
+    args = _memory_update_table_args(rng, n, m, 32, gates=1)
+    widx = np.asarray(args[4])
+    if m > n:
+        assert len(np.unique(np.asarray(args[3])[widx < n])) < int(m * 0.8)
+    got = ops.memory_update_table(*args, interpret=True, clip=1.0,
+                                  delta_mode=delta_mode, cell="rnn")
+    want = _split_rnn_path(args, 1.0, delta_mode)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5)
+    oracle = ref.memory_update_table_ref(*args, clip=1.0,
+                                         delta_mode=delta_mode, cell="rnn")
+    for g, w in zip(got, oracle):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5)
+
+
+def test_memory_update_table_rnn_gradients_match_oracle():
+    """The RNN form's custom VJP vs jax.grad of its oracle."""
+    import functools
+    rng = np.random.default_rng(58)
+    args = _memory_update_table_args(rng, 40, 30, 16, gates=1)
+    argnums = (0, 1, 2, 5, 6, 7, 8, 9, 10, 11)
+
+    def loss(fn):
+        def f(*a):
+            outs = fn(*a, clip=1.0, delta_mode="transition", cell="rnn")
+            return sum(jnp.sum(o ** 2) for o in outs)
+        return f
+
+    gk = jax.grad(loss(functools.partial(ops.memory_update_table,
+                                         interpret=True)),
+                  argnums=argnums)(*args)
+    gr = jax.grad(loss(ref.memory_update_table_ref), argnums=argnums)(*args)
+    for a, b in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+@pytest.mark.parametrize("cell,logged", [("gru", "memory_update_table"),
+                                         ("rnn", "memory_update_table_rnn")])
+def test_memory_update_table_form_in_dispatch_log(cell, logged):
+    """The dispatch log keys each form of the table kernel by the name its
+    pallas_call carries; an unknown cell is refused."""
+    from repro.kernels import memory_update as mu
+    rng = np.random.default_rng(59)
+    args = _memory_update_table_args(rng, 20, 16, 8,
+                                     gates=mu.GATES[cell])
+    ops.reset_dispatch_log()
+    ops.memory_update_table(*args, mode="oracle", cell=cell)
+    assert ops.dispatch_log() == {logged: {"oracle": 1}}
+    assert mu.table_kernel_name(cell) == logged
+    with pytest.raises(ValueError, match="memory cell"):
+        mu.table_kernel_name("lstm")
 
 
 # ---------------------------------------------------------------------------

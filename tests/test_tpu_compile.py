@@ -7,7 +7,8 @@ split a tile, layouts it cannot map — so every kernel the training and
 serving path dispatches is compiled here at the widths of the paper's
 model (`configs/tgn_pres.py::CONFIG` on the JODIE-Wikipedia node table)
 and of `PRODUCTION`, and the compiled text must hold the kernel
-(`tpu_custom_call`), named after the kernel's registry name. Nothing
+(`tpu_custom_call`), named after the kernel's registry name (the fused
+table kernel's RNN form after its form, `memory_update_table_rnn`). Nothing
 runs, so this says nothing about results or speed.
 
 The topology is described inside a module fixture, never at import: only
@@ -17,6 +18,7 @@ import this file.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import re
 
@@ -65,11 +67,16 @@ def _case(kernel: str, cfg, spec):
     f32, i32 = jnp.float32, jnp.int32
     n, d, din = cfg.n_nodes, cfg.d_mem, cfg.d_msg
     dt, kk, e, m = cfg.d_time, cfg.n_neighbors, cfg.d_embed, ROWS
-    if kernel == "memory_update_table":
-        return mu._memory_update_table_pallas, (
+    if kernel.startswith("memory_update_table"):
+        # the GRU form, or the RNN form (`memory_update_table_rnn`)
+        cell = kernel.rpartition("_")[2] if kernel.endswith("_rnn") \
+            else "gru"
+        gd = mu.GATES[cell] * d
+        return functools.partial(mu._memory_update_table_pallas,
+                                 cell=cell), (
             spec((n, d)), spec((n,)), spec((m, din)), spec((m,), i32),
-            spec((m,), i32), spec((m,)), spec((din, 3 * d)),
-            spec((d, 3 * d)), spec((3 * d,)), spec((m, d)), spec((m,)),
+            spec((m,), i32), spec((m,)), spec((din, gd)),
+            spec((d, gd)), spec((gd,)), spec((m, d)), spec((m,)),
             spec(()))
     if kernel == "embed_attn":
         return (lambda *a, interpret: ea._embed_attn_pallas(
@@ -97,7 +104,8 @@ def _case(kernel: str, cfg, spec):
 @pytest.mark.parametrize("widths", sorted(WIDTHS))
 @pytest.mark.parametrize("kernel", ["memory_update_table", "embed_attn",
                                     "pres_filter", "pres_predict",
-                                    "gru_cell", "link_score"])
+                                    "gru_cell", "link_score",
+                                    "memory_update_table_rnn"])
 def test_kernel_compiles_for_v5e(one_chip, kernel, widths):
     def spec(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
