@@ -458,6 +458,42 @@ def make_eval_step(cfg: MDGNNConfig):
     return _replicating_inputs(cfg, jax.jit(eval_step), n_carry=2)
 
 
+# How many steps behind its dispatch a step's outputs are pulled: step i's
+# `train.pull` converts step i - PULL_LAG's outputs to numpy, so the chip
+# keeps the steps in between queued while the host waits on the oldest.
+# Chosen from chip runs of 1 to 4 (PERF.md §6).
+PULL_LAG = 2
+
+
+class _LaggedPull:
+    """The epoch's per-step outputs on their way to the host. `push` starts
+    a step's host copies (`copy_to_host_async`) and converts the oldest
+    queued step to numpy once more than `PULL_LAG` are queued; `drain`
+    converts the rest and returns every step's numpy tuple, oldest first.
+    Nothing runs on the device: no program, and no wait but for the oldest
+    step."""
+
+    def __init__(self):
+        self.queued = []
+        self.pulled = []
+
+    def push(self, outs: tuple) -> None:
+        for x in outs:
+            x.copy_to_host_async()
+        self.queued.append(outs)
+        if len(self.queued) > PULL_LAG:
+            self._pull_oldest()
+
+    def drain(self) -> list:
+        while self.queued:
+            self._pull_oldest()
+        return self.pulled
+
+    def _pull_oldest(self) -> None:
+        self.pulled.append(tuple(np.asarray(x)
+                                 for x in self.queued.pop(0)))
+
+
 @dataclasses.dataclass
 class EpochResult:
     ap: float
@@ -480,9 +516,10 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
 
     `batches` may be a materialized list OR a lazy/prefetching iterator
     (`EventStream.prefetch_batches`) — the driver consumes it pairwise.
-    Loss scalars stay on device until epoch end (no per-step `float(...)`
-    sync); logits are pulled to numpy as they arrive so device memory stays
-    bounded at one step's worth.
+    Each step's loss and logits are pulled to numpy `PULL_LAG` steps after
+    its dispatch (`_LaggedPull`), so the host never waits on the step it
+    just dispatched and device memory stays bounded at a few steps' worth;
+    the epoch end converts the last ones.
 
     The negatives are drawn inside the compiled step (`NegativeDraw`): the
     host forwards the key the previous step returned and the destination
@@ -491,10 +528,11 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
 
     Host spans (obs.trace, recorded when enabled) tile each step: the
     draw's forwarding (`train.sample`), the step call until it returns
-    (`train.dispatch`), the logits pull (`train.pull`), each carrying the
-    step index; then the epoch-end work (`train.epoch_end`)."""
+    (`train.dispatch`), the lagged pull (`train.pull`: step i - PULL_LAG's
+    outputs), each carrying the step index; then the epoch-end work
+    (`train.epoch_end`: the last PULL_LAG pulls, `EpochObs.finish`, AP)."""
     t0 = time.perf_counter()
-    losses, pos_all, neg_all = [], [], []
+    pull = _LaggedPull()
     obs = obs_metrics.EpochObs()
     draw = NegativeDraw.start(key, dst_range)
     it = iter(batches)
@@ -508,9 +546,7 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
                     params, opt_state, state, prev_batch, batch, draw)
                 key = m.pop("neg_key")
             with obs_trace.span("train.pull", step=i):
-                losses.append(m["loss"])               # device scalar
-                pos_all.append(np.asarray(m["logit_p"]))
-                neg_all.append(np.asarray(m["logit_n"]))
+                pull.push((m["loss"], m["logit_p"], m["logit_n"]))
                 obs.step(m)                            # device values only
             prev_batch = batch
     finally:
@@ -519,8 +555,11 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
         if close is not None:
             close()
     with obs_trace.span("train.epoch_end"):
-        losses = [float(x) for x in losses]            # one host sync
-        route_overflow, obs_out = obs.finish()         # one more (batched)
+        steps = pull.drain()
+        losses = [float(loss) for loss, _, _ in steps]
+        pos_all = [p for _, p, _ in steps]
+        neg_all = [n for _, _, n in steps]
+        route_overflow, obs_out = obs.finish()         # one batched fetch
         ap = metrics_lib.average_precision(np.concatenate(pos_all),
                                            np.concatenate(neg_all))
         aps = [metrics_lib.average_precision(p, n)
@@ -533,9 +572,10 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
 
 def evaluate(params, state, batches, cfg: MDGNNConfig, eval_step, key, dst_range):
     """Evaluation pass; `batches` may be a list or a (prefetching) iterator.
-    Negatives are drawn inside the step and host spans tile each step, as
-    in `run_epoch`."""
-    pos_all, neg_all = [], []
+    Negatives are drawn inside the step, the logits are pulled
+    `PULL_LAG` steps late and host spans tile each step, as in
+    `run_epoch`."""
+    pull = _LaggedPull()
     draw = NegativeDraw.start(key, dst_range)
     it = iter(batches)
     try:
@@ -547,15 +587,16 @@ def evaluate(params, state, batches, cfg: MDGNNConfig, eval_step, key, dst_range
                 state, lp, ln, key = eval_step(params, state, prev_batch,
                                                batch, draw)
             with obs_trace.span("train.pull", step=i):
-                pos_all.append(np.asarray(lp))
-                neg_all.append(np.asarray(ln))
+                pull.push((lp, ln))
             prev_batch = batch
     finally:
         close = getattr(it, "close", None)
         if close is not None:
             close()
     with obs_trace.span("train.epoch_end"):
-        pos, neg = np.concatenate(pos_all), np.concatenate(neg_all)
+        steps = pull.drain()
+        pos = np.concatenate([p for p, _ in steps])
+        neg = np.concatenate([n for _, n in steps])
         ap = metrics_lib.average_precision(pos, neg)
         auc = metrics_lib.roc_auc(pos, neg)
     return state, ap, auc

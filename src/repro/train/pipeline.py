@@ -272,9 +272,9 @@ def run_epoch(params, opt_state, state, batches, cfg: MDGNNConfig,
     split per step in the same order as loop.run_epoch, so negatives are
     identical across depths (the sweep compares schedules, not samples).
     Per-step metrics stay on device; the single host sync happens at epoch
-    end (the sequential loop also defers its loss syncs to epoch end, but
-    still pulls each step's logits — and the scan engine, repro.train.scan,
-    amortizes even that to once per macro-batch). Host spans carry the
+    end (the sequential loop instead pulls each step's loss and logits
+    `loop.PULL_LAG` steps after its dispatch, and the scan engine,
+    repro.train.scan, once per macro-batch). Host spans carry the
     sequential loop's names; `train.pull` here only collects the step's
     device outputs, which `train.epoch_end` pulls."""
     if cfg.pipeline_depth == 0:
